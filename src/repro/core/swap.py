@@ -9,8 +9,8 @@ Phase 2: W independent small-batch workers from the common phase-1 model,
          ensemble*: parameters stacked on a leading W axis and the whole
          scanned epoch advanced in one program. On a worker mesh the
          engine lowers SHARDED (``EpochRunner(engine="sharded")``:
-         ``vmap(..., spmd_axis_name="worker")`` with in/out shardings
-         pinned to ``ensemble_shardings``) so the compiled program has no
+         ``shard_map`` over ``worker`` with in/out shardings pinned to
+         ``ensemble_shardings``) so the compiled program has no
          cross-worker collectives and deploys with the worker axis across
          hosts; without a mesh the same chunk runs as the plain-vmap
          oracle. ``repro.dist.DistConfig`` selects mesh + engine.

@@ -1,18 +1,8 @@
-"""Distribution subsystem: meshes, sharding rules, HLO collective checks.
-
-Importing this package installs the small jax compatibility shims (see
-``repro.dist.compat``) needed to run the sharding API on the pinned
-JAX 0.4.37 — callers that create meshes with ``axis_types=`` get them
-accepted (and ignored) instead of a ``TypeError``.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
-
-from repro.dist import sharding  # noqa: E402,F401
-from repro.dist.config import (  # noqa: E402,F401
+"""Distribution subsystem: meshes, sharding rules, HLO collective checks."""
+from repro.dist import sharding  # noqa: F401
+from repro.dist.config import (  # noqa: F401
     DistConfig, add_dist_args, parse_mesh, resolve_dist,
 )
-from repro.dist.sharding import (  # noqa: E402,F401
+from repro.dist.sharding import (  # noqa: F401
     assert_no_cross_worker_collectives,
 )

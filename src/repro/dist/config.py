@@ -10,10 +10,10 @@ knobs rather than call-site arguments). One frozen dataclass describes
     config is hashable and JSON round-trippable; ``make_mesh()`` builds the
     runtime ``jax.sharding.Mesh`` from whatever devices exist),
   * the phase-2 engine   — ``phase2_engine``: "sharded" lowers the ensemble
-    epoch as ONE sharded-jit program (``vmap(..., spmd_axis_name='worker')``
-    with pinned in/out shardings — the worker axis of every intermediate is
-    fixed in the partitioner, which is what keeps the lowering free of
-    cross-worker collectives); "vmap" is the plain single-device vmap that
+    epoch as ONE program with the worker axis manual (``shard_map`` over
+    ``worker`` with pinned in/out shardings — each worker block runs its
+    own workers, which keeps the lowering free of cross-worker collectives
+    and lets Pallas kernels lower per block); "vmap" is the plain vmap that
     stays as the bitwise equivalence oracle; "auto" picks "sharded" iff the
     mesh has a worker axis,
   * donation policy      — ``donate_state``: whether epoch chunks donate the

@@ -79,8 +79,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
         # logsumexp for the backward pass; 0 for empty rows so that
         # exp(s - lse) underflows to 0 there (s stays at NEG_INF)
-        lse_ref[0, ...] = jnp.where(empty[:, 0], 0.0,
-                                    m_ref[:, 0] + jnp.log(l[:, 0]))
+        lse_ref[0, 0] = jnp.where(empty[:, 0], 0.0,
+                                  m_ref[:, 0] + jnp.log(l[:, 0]))
 
 
 def _layout(q, k, v, block_q, block_k, interpret):
@@ -144,7 +144,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
         return (bh, qi, 0)
 
     def lse_map(bh, qi, ki):
-        return (bh, qi)
+        return (bh, 0, qi)
 
     def kv_map(bh, qi, ki):
         b, h = bh // H, bh % H
@@ -156,7 +156,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
             block_q=block_q, block_k=block_k, q_offset=q_offset, kv_len=Skv),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, Sqp), jnp.float32),
         ),
         grid=grid,
         in_specs=[
@@ -166,7 +166,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, Dp), q_map),
-            pl.BlockSpec((1, block_q), lse_map),
+            pl.BlockSpec((1, 1, block_q), lse_map),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max
@@ -177,7 +177,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
     )(qf, kf, vf)
 
     out = jnp.swapaxes(out[:, :Sq, :D].reshape(B, H, Sq, D), 1, 2)
-    lse = jnp.swapaxes(lse[:, :Sq].reshape(B, H, Sq), 1, 2)
+    lse = jnp.swapaxes(lse[:, 0, :Sq].reshape(B, H, Sq), 1, 2)
     return out, lse
 
 
@@ -212,8 +212,8 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                 # (block_q,)
-    delta = delta_ref[0]                             # (block_q,)
+    lse = lse_ref[0, 0]                              # (block_q,)
+    delta = delta_ref[0, 0]                          # (block_q,)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
     mask = _mask(qi, ki, block_q, block_k, q_offset, q_len, kv_len, causal,
@@ -245,8 +245,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = lse_ref[0, 0]
+    delta = delta_ref[0, 0]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
     mask = _mask(qi, ki, block_q, block_k, q_offset, q_len, kv_len, causal,
@@ -286,10 +286,10 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
     dof = _layout(do, k, v, block_q, block_k, interpret)[0]
     # delta = rowsum(dO * O) — cheap elementwise, computed outside
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
-    deltaf = jnp.pad(jnp.swapaxes(delta, 1, 2).reshape(B * H, Sq),
-                     ((0, 0), (0, Sqp - Sq)))
-    lsef = jnp.pad(jnp.swapaxes(lse, 1, 2).reshape(B * H, Sq),
-                   ((0, 0), (0, Sqp - Sq)))
+    deltaf = jnp.pad(jnp.swapaxes(delta, 1, 2).reshape(B * H, 1, Sq),
+                     ((0, 0), (0, 0), (0, Sqp - Sq)))
+    lsef = jnp.pad(jnp.swapaxes(lse, 1, 2).reshape(B * H, 1, Sq),
+                   ((0, 0), (0, 0), (0, Sqp - Sq)))
     nq, nk = Sqp // block_q, Skvp // block_k
 
     kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
@@ -299,7 +299,7 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
         return (bh, qi, 0)
 
     def r_map(bh, qi, ki):
-        return (bh, qi)
+        return (bh, 0, qi)
 
     def kv_map(bh, qi, ki):
         b, h = bh // H, bh % H
@@ -314,8 +314,8 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, block_k, Dp), kv_map),
             pl.BlockSpec((1, block_k, Dp), kv_map),
             pl.BlockSpec((1, block_q, Dp), q_map),
-            pl.BlockSpec((1, block_q), r_map),
-            pl.BlockSpec((1, block_q), r_map),
+            pl.BlockSpec((1, 1, block_q), r_map),
+            pl.BlockSpec((1, 1, block_q), r_map),
         ],
         out_specs=pl.BlockSpec((1, block_q, Dp), q_map),
         scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)],
@@ -329,7 +329,7 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
 
     def r_map2(bkv, ki, gq):
         b, hkv = bkv // KVH, bkv % KVH
-        return (b * H + hkv * G + gq // nq, gq % nq)
+        return (b * H + hkv * G + gq // nq, 0, gq % nq)
 
     def kv_map2(bkv, ki, gq):
         return (bkv, ki, 0)
@@ -346,8 +346,8 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             pl.BlockSpec((1, block_k, Dp), kv_map2),
             pl.BlockSpec((1, block_k, Dp), kv_map2),
             pl.BlockSpec((1, block_q, Dp), q_map2),
-            pl.BlockSpec((1, block_q), r_map2),
-            pl.BlockSpec((1, block_q), r_map2),
+            pl.BlockSpec((1, 1, block_q), r_map2),
+            pl.BlockSpec((1, 1, block_q), r_map2),
         ],
         out_specs=(
             pl.BlockSpec((1, block_k, Dp), kv_map2),

@@ -96,10 +96,8 @@ def _blockwise_reference(q, k, v, *, causal, window, scale, q_offset, chunk):
     return out.astype(q.dtype)
 
 
-# JAX 0.4.37: custom_vjp has no nondiff_argnames; positional argnums (all
-# static/hashable: bools, ints, float-or-None, frozen DesignPoint) express
-# the same thing. The bwd signature receives them first, per the argnums
-# convention.
+# nondiff args (all static/hashable: bools, ints, float-or-None, frozen
+# DesignPoint) come first in the bwd signature, per the argnums convention.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _pallas_attention(q, k, v, causal, window, scale, q_offset, design,
                       interpret):

@@ -140,9 +140,8 @@ def _intra_bwd(variant, xp, dtp, A, Bmp, Cmp, d_yi, d_st, d_cum, c, design,
                                 chunk=c, interpret=interpret)
 
 
-# JAX 0.4.37: custom_vjp has no nondiff_argnames; chunk, variant, design and
-# interpret (args 7-10, all static/hashable) become positional nondiff
-# argnums — bwd takes them first.
+# chunk, variant, design and interpret (args 7-10, all static/hashable) are
+# nondiff argnums — bwd takes them first.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def _pallas_ssd(x, dt, A, Bm, Cm, D, init_state, chunk, variant, design,
                 interpret):

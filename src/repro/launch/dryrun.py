@@ -132,8 +132,6 @@ def _jit_for_shape(model: Model, cfg: ModelConfig, shape: ShapeConfig, mesh,
 
 def _terms_from_compiled(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # JAX 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),
@@ -334,18 +332,12 @@ def run_one(arch: str, shape_name: str, mesh_kind: str,
 def _ensemble_sharded_lower(cfg: ModelConfig, shape: ShapeConfig, mesh,
                             n_workers: int, n_steps: int = 2):
     """Phase-2 lowered the way the PRODUCTION engine runs it: ONE
-    sharded-jit program over the whole worker mesh —
-    ``EpochRunner(engine="sharded")``, i.e. ``vmap(scan(step),
-    spmd_axis_name="worker")`` with the carried TrainState pinned to
-    ``ensemble_shardings``. ``spmd_axis_name`` stamps the worker axis onto
-    every vmapped intermediate inside the partitioner, which keeps DENSE
-    transformer chunks collective-free (internlm2-1.8b train_4k at 256
-    devices: zero collective groups in the compiled HLO — the weekly CI
-    audit). It does NOT close the MoE scatter/top_k escape the bare-vmap
-    form had (see ``_ensemble_jit``'s history note): granite-moe under
-    this lowering still emits a cross-worker all-reduce, which the
-    downstream audit catches and fails loudly. MoE archs therefore audit
-    (and deploy) via the per-worker-block ``programs`` engine.
+    program over the whole worker mesh — ``EpochRunner(engine="sharded")``,
+    i.e. ``shard_map`` over ``worker`` of ``vmap(scan(step))`` with the
+    carried TrainState pinned to ``ensemble_shardings``. The worker axis
+    is manual, so the partitioner cannot place a collective across worker
+    blocks; the downstream audit checks the compiled HLO all the same. The
+    weekly CI audit runs it on internlm2-1.8b train_4k at 256 devices.
 
     Returns ``(lowered, n_steps)`` — a lowered (not compiled) chunk of
     ``n_steps`` scanned train steps over a tiny zero-token dataset (the
@@ -397,16 +389,7 @@ def _ensemble_jit(model: Model, cfg: ModelConfig, shape: ShapeConfig, mesh,
     separate single-GPU processes). Cross-worker collectives are impossible
     by construction — each program only spans its own block's devices; the
     assert downstream re-verifies that every HLO replica group stays within
-    one block.
-
-    (We first tried a single global program — vmap with a sharded worker
-    axis, then partial-manual shard_map. The vmap form lets the SPMD
-    partitioner escape across the worker axis on scatter/top_k ops (MoE
-    router probs, kv=1 attention all-gathers, 16-160MB each); the shard_map
-    form CHECK-crashes XLA's spmd_partitioner on the same archs. Both
-    observations are recorded in EXPERIMENTS.md §Dry-run. Independent
-    programs are also operationally truer: phase-2 workers shouldn't share
-    a lockstep dispatch loop.)"""
+    one block. The one-program form is ``_ensemble_sharded_lower``."""
     opt_cfg = OptimizerConfig(kind="sgd")
     opt_init, train_step = make_lm_train_step(
         model, opt_cfg, schedule_fn(ScheduleConfig(kind="const")))
@@ -451,7 +434,7 @@ def main():
                          "independent programs (deployment-shaped, safe "
                          "for every arch) or the production sharded-jit "
                          "engine (one global program, "
-                         "vmap+spmd_axis_name with pinned shardings)")
+                         "shard_map over worker with pinned shardings)")
     ap.add_argument("--workers", type=int, default=8)
     ap.add_argument("--precision", default="float32",
                     choices=["float32", "bfloat16"],

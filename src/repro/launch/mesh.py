@@ -9,8 +9,6 @@ from __future__ import annotations
 
 import jax
 
-import repro.dist  # noqa: F401  (compat shims: AxisType / axis_types kwarg)
-
 
 def _mk(shape, axes):
     return jax.make_mesh(

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.checkpoint.io import load_pytree
 from repro.configs import registry
 from repro.dist.config import DistConfig, add_dist_args
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 
 
@@ -241,6 +242,7 @@ def main():
     args = ap.parse_args()
     args.dist = DistConfig.from_args(args)
     args.dist.initialize()
+    enable_compile_cache()
     if args.dump_dist_config:
         args.dist.to_json(args.dump_dist_config)
         print(f"wrote resolved DistConfig to {args.dump_dist_config}")

@@ -56,6 +56,7 @@ from repro.core.adapters import LMAdapter
 from repro.core.swap import SWAP
 from repro.data.pipeline import Loader, make_markov_lm
 from repro.dist.config import DistConfig, add_dist_args
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -112,6 +113,7 @@ def main():
     dist = DistConfig.from_args(args, n_workers_default=4)
     # multi-host: join the jax.distributed cluster BEFORE any device query
     dist.initialize()
+    enable_compile_cache()
     if args.dump_dist_config:
         dist.to_json(args.dump_dist_config)
         print(f"wrote resolved DistConfig to {args.dump_dist_config}")

@@ -17,13 +17,14 @@ epoch-granular runner:
     chunk inside ONE jit (vmapped over the worker axis for phase 2). Each
     scanned step gathers its batch in-trace via ``Loader.batch_in_trace``,
     so no per-step host work or host->device transfer remains. On a worker
-    mesh the ensemble runner lowers as a SHARDED-JIT program
-    (``engine="sharded"``): ``vmap(..., spmd_axis_name="worker")`` with the
-    in/out state shardings pinned to ``dist.sharding.ensemble_shardings``,
-    so the partitioner carries the worker axis on every vmapped
-    intermediate and the compiled program contains no cross-worker
-    collectives (checked by ``assert_no_cross_worker_collectives``). The
-    plain-vmap form stays as the bitwise equivalence oracle.
+    mesh the ensemble runner lowers as ONE program in which each worker
+    block runs its own workers (``engine="sharded"``): ``shard_map`` over
+    the ``worker`` axis with the in/out state shardings pinned to
+    ``dist.sharding.ensemble_shardings``, so the compiled program contains
+    no cross-worker collectives (checked by
+    ``assert_no_cross_worker_collectives``) and Pallas kernels, which XLA
+    cannot partition, lower inside each block. The plain-vmap form stays as
+    the bitwise equivalence oracle.
   * ``run_phase`` — the thin host driver: one compiled call per epoch,
     early-exit on the accuracy EMA at *epoch boundaries* (the streaming
     equivalent of the paper's per-epoch train-accuracy check), metric-log
@@ -101,6 +102,37 @@ def stack_train_state(stacked_bundle, stacked_opt_state, n_workers: int,
             scale if scale is not None else default_scale_state(), n_workers))
 
 
+def _require_auto_axes(mesh) -> None:
+    if jax.sharding.AxisType.Explicit in getattr(mesh, "axis_types", ()):
+        raise ValueError(
+            f"EpochRunner needs a mesh with Auto axes, got axis_types "
+            f"{mesh.axis_types}: build it with DistConfig.make_mesh / "
+            f"launch.mesh, or jax.make_mesh(..., axis_types=(AxisType.Auto,"
+            f" ...))")
+
+
+def _require_auto_inputs(*trees) -> None:
+    for leaf in jax.tree_util.tree_leaves(trees):
+        mesh = getattr(getattr(leaf, "sharding", None), "mesh", None)
+        if mesh is not None:
+            _require_auto_axes(mesh)
+
+
+def _resize_rows(mesh, tree, n_rows: int):
+    """Every leaf's leading (worker) axis cut or grown to ``n_rows`` (growth
+    repeats the last row), in one program whose output is placed by
+    ``ensemble_shardings``, so no device materialises more than its share."""
+    from repro.dist.sharding import ensemble_shardings
+
+    def resize(t):
+        return jax.tree_util.tree_map(
+            lambda a: a[:n_rows] if a.shape[0] >= n_rows else jnp.concatenate(
+                [a, jnp.repeat(a[-1:], n_rows - a.shape[0], axis=0)]), t)
+
+    out = ensemble_shardings(mesh, jax.eval_shape(resize, tree))
+    return jax.jit(resize, out_shardings=out)(tree)
+
+
 class EpochRunner:
     """jit(lax.scan(train_step)) over epoch-sized chunks, with the batch
     gathered in-trace.
@@ -115,20 +147,25 @@ class EpochRunner:
     resolves it; non-ensemble runners ignore it):
 
       * ``"vmap"`` (default) — plain ``jax.vmap``; single-device oracle.
-      * ``"sharded"`` — ``jax.vmap(..., spmd_axis_name="worker")`` jitted
-        with ``in_shardings``/``out_shardings`` pinned to
-        ``ensemble_shardings(mesh, ...)``. ``spmd_axis_name`` stamps the
-        worker axis onto every vmapped intermediate inside the partitioner,
-        so per-worker content cannot be re-gathered across workers — the
-        lowering the no-cross-worker-collective audit runs against, and the
-        form a real worker mesh (worker axis across hosts) executes.
+      * ``"sharded"`` — ``jax.shard_map`` over the mesh's ``worker`` axis
+        of a ``jax.vmap`` over the block's own workers, jitted with
+        ``in_shardings``/``out_shardings`` pinned to
+        ``ensemble_shardings(mesh, ...)``. The worker axis is manual, so
+        per-worker content cannot be re-gathered across workers, and a
+        Mosaic kernel in the step lowers per block (XLA refuses to
+        partition one across a sharded operand). This is the lowering the
+        no-cross-worker-collective audit runs against, and the form a real
+        worker mesh executes. Other mesh axes stay automatic inside each
+        block, where a Mosaic kernel would still need its own partitioning.
         Requires ``mesh`` with a ``worker`` axis. Bitwise-identical to the
         ``"vmap"`` engine on the same mesh (asserted in
         tests/test_sharded_engine.py).
 
-        (``shard_map`` with auto-managed inner axes was tried first and
-        CHECK-crashes XLA's spmd_partitioner on JAX 0.4.37 — see
-        ``launch.dryrun._ensemble_jit``'s history note.)
+    Meshes and placed inputs must have Auto axes (GSPMD placement), as
+    ``DistConfig.make_mesh`` and ``launch.mesh`` build them. The in-trace
+    batch gather and permutation draw do not trace or lower under
+    Explicit axes, the default of a bare ``jax.make_mesh``, so the runner
+    rejects those with a ValueError instead.
 
     Compiled programs are cached per chunk length; the input state is
     donated (``donate=False`` — DistConfig.donate_state — keeps the
@@ -161,6 +198,8 @@ class EpochRunner:
                 raise ValueError("engine='sharded' needs a mesh with a "
                                  "'worker' axis (see DistConfig.make_mesh / "
                                  "launch.mesh.make_worker_mesh)")
+        if mesh is not None:
+            _require_auto_axes(mesh)
         self.step_fn = step_fn
         self.loader = loader
         self.ema_beta = ema_beta
@@ -175,6 +214,7 @@ class EpochRunner:
         fn = self._compiled.get(n_steps)
         if fn is not None:
             return fn
+        _require_auto_inputs(state, worker)
         step_fn, loader, beta = self.step_fn, self.loader, self.ema_beta
 
         def run_chunk(state: TrainState, worker):
@@ -199,21 +239,35 @@ class EpochRunner:
 
         donate = (0,) if self.donate else ()
         if self.ensemble and self.engine == "sharded":
-            # ONE sharded-jit program: spmd_axis_name pins the worker axis
-            # of every vmapped intermediate in the partitioner, and the
-            # explicit in/out shardings pin the carried state, so nothing
-            # can be re-gathered across worker blocks. Shardings are
+            # ONE program, each worker block running its own workers: the
+            # worker axis is manual inside shard_map, and the explicit
+            # in/out shardings pin the carried state, so nothing can be
+            # re-gathered across worker blocks. Shardings are
             # derived from the example state/worker (ShapeDtypeStructs
             # suffice — only shapes matter), whose structure is fixed for
             # the runner's lifetime.
             if state is None or worker is None:
                 raise ValueError("sharded engine needs the example state/"
                                  "worker to derive shardings")
+            if self._worker_pad(worker):
+                raise ValueError(
+                    f"sharded engine: {worker.shape[0]} workers do not "
+                    f"divide over the mesh's worker axis of "
+                    f"{self.mesh.shape['worker']} (run_chunk pads them)")
             from repro.dist.sharding import ensemble_shardings
             st_sh = ensemble_shardings(self.mesh, state)
             wk_sh = ensemble_shardings(self.mesh, worker)
-            fn = jax.jit(jax.vmap(run_chunk, spmd_axis_name="worker"),
-                         in_shardings=(st_sh, wk_sh),
+
+            def specs(tree):
+                return jax.tree_util.tree_map(lambda s: s.spec, tree)
+
+            # metrics are (W, n_steps) per leaf: they follow the worker ids
+            local = jax.shard_map(
+                jax.vmap(run_chunk), mesh=self.mesh,
+                in_specs=(specs(st_sh), specs(wk_sh)),
+                out_specs=(specs(st_sh), wk_sh.spec),
+                axis_names={"worker"}, check_vma=False)
+            fn = jax.jit(local, in_shardings=(st_sh, wk_sh),
                          out_shardings=(st_sh, None),
                          donate_argnums=donate)
         else:
@@ -223,11 +277,33 @@ class EpochRunner:
         self._compiled[n_steps] = fn
         return fn
 
+    def _worker_pad(self, worker) -> int:
+        """Rows the sharded engine adds so W divides the worker axis."""
+        if not (self.ensemble and self.engine == "sharded"):
+            return 0
+        return -worker.shape[0] % self.mesh.shape["worker"]
+
     def run_chunk(self, state: TrainState, worker, n_steps: int):
         """Advance ``n_steps`` inside one compiled call. Returns
         (new_state, metrics) with every metric stacked over the step axis
-        (``(n_steps,)`` leaves; ``(W, n_steps)`` for ensembles)."""
-        return self._chunk_fn(n_steps, state, worker)(state, worker)
+        (``(n_steps,)`` leaves; ``(W, n_steps)`` for ensembles).
+
+        The sharded engine needs W to be a multiple of the mesh's worker
+        axis. Where it is not (an ensemble that lost workers), the last
+        worker is repeated up to the next multiple, so each worker block
+        still trains only its own share; the copies are dropped afterwards
+        and the state goes back to ``ensemble_shardings`` of the W workers
+        (replicated on the worker axis, since W does not divide it)."""
+        pad = self._worker_pad(worker)
+        if not pad:
+            return self._chunk_fn(n_steps, state, worker)(state, worker)
+        n = worker.shape[0]
+        padded = _resize_rows(self.mesh, (state, worker), n + pad)
+        if self.donate:  # free the caller's copy before the chunk runs
+            jax.tree_util.tree_map(lambda a: a.delete(), state)
+        state, metrics = self._chunk_fn(n_steps, *padded)(*padded)
+        return (_resize_rows(self.mesh, state, n),
+                jax.tree_util.tree_map(lambda a: a[:n], metrics))
 
     def lower_chunk(self, state, worker, n_steps: int):
         """AOT-lower one chunk without executing it (``state``/``worker``

@@ -16,7 +16,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-import repro.dist  # noqa: E402,F401  (installs the JAX 0.4.37 compat shims)
+import repro.dist  # noqa: E402,F401  (sharding subsystem, imported once up front)
 
 # The CI image has no hypothesis; install the deterministic stub only when
 # the real library is absent (see repro/testing/hypothesis_stub.py).

@@ -39,7 +39,10 @@ def test_logical_constraint_identity_without_mesh():
 
 def test_logical_constraint_applies_under_mesh():
     n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    # Auto axes, as DistConfig.make_mesh builds it: constraints name Auto
+    # axes only (a bare jax.make_mesh defaults to Explicit axes)
+    mesh = jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     x = jnp.zeros((n * 2, 16))
     with set_mesh(mesh):
         out = jax.jit(lambda a: logical_constraint(a, ("batch",)))(x)

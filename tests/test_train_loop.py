@@ -205,7 +205,8 @@ def test_phase2_scan_epoch_has_no_cross_worker_collectives():
     if len(jax.devices()) < W * PER_WORKER:
         pytest.skip(f"needs {W * PER_WORKER} devices "
                     f"(conftest forces 8 on CPU hosts)")
-    mesh = jax.make_mesh((W, 2, 2), ("worker", "data", "model"))
+    mesh = jax.make_mesh((W, 2, 2), ("worker", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
     cfg = registry.get_smoke_config("internlm2-1.8b")
     adapter = LMAdapter(cfg, OptimizerConfig(kind="sgd"))
