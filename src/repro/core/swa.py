@@ -4,11 +4,11 @@ streaming average (swa_avg kernel path on TPU), BN recompute at the end.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict
 
 import jax
 
+from repro import obs
 from repro.configs.base import SWAConfig
 from repro.core.averaging import StreamingAverage
 from repro.core.schedules import schedule_fn as make_schedule
@@ -37,23 +37,23 @@ class SWA:
             else adapter.init_opt(bundle)
         scale = default_scale_state()   # SWA baseline trains plain f32
 
-        t0 = time.perf_counter()
-        avg = StreamingAverage()
-        total_steps = cfg.n_samples * cfg.cycle_steps
-        for step in range(total_steps):
-            batch = loader.batch(step)
-            bundle, opt_state, scale, metrics = step_fn(
-                bundle, opt_state, batch, step, scale)
-            if (step + 1) % cfg.cycle_steps == 0:
-                avg.add(bundle["params"])
-        last_acc = adapter.eval_accuracy(bundle, self.test_loader)
-        final = adapter.finalize(avg.value(), loader)
-        t1 = time.perf_counter()
+        with obs.span("SWA.run") as span:
+            avg = StreamingAverage()
+            total_steps = cfg.n_samples * cfg.cycle_steps
+            for step in range(total_steps):
+                batch = loader.batch(step)
+                bundle, opt_state, scale, metrics = step_fn(
+                    bundle, opt_state, batch, step, scale)
+                if (step + 1) % cfg.cycle_steps == 0:
+                    avg.add(bundle["params"])
+            last_acc = adapter.eval_accuracy(bundle, self.test_loader)
+            with obs.span("SWA.bn_recompute"):
+                final = adapter.finalize(avg.value(), loader)
         return {
             "before_avg_test_acc": last_acc,
             "after_avg_test_acc": adapter.eval_accuracy(final,
                                                         self.test_loader),
-            "time": t1 - t0,
+            "time": span.seconds,
             "n_samples": avg.n,
             "final_bundle": final,
             "last_bundle": bundle,

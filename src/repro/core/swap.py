@@ -33,13 +33,13 @@ mid-phase-1 or mid-phase-2 (see ``repro.checkpoint.state``).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.state import (
     Checkpointer, checkpoint_workers, find_resume_point, list_checkpoints,
     load_train_state, shrink_worker_axis, state_step,
@@ -229,58 +229,60 @@ class SWAP:
             if (resume and cfg.checkpoint_dir) else None
 
         # ---------------- phase 1: large batch, synchronous --------------
-        t0 = time.perf_counter()
-        bundle = adapter.init(key)
-        p1 = SGDRun(adapter, cfg.phase1, self.train_arrays, seed=cfg.seed,
-                    dist=self.dist)
-        if resume_pt is not None and resume_pt["tag"] in ("phase1_final",
-                                                          "phase2"):
-            # phase 1 finished in a previous process: restore its final
-            # state + summary metrics from the phase1_final snapshot
-            finals = [c for c in list_checkpoints(cfg.checkpoint_dir)
-                      if c["tag"] == "phase1_final"]
-            if not finals:
-                raise ValueError(
-                    f"cannot resume {resume_pt['tag']} from "
-                    f"{cfg.checkpoint_dir!r}: no phase1_final snapshot")
-            state1 = load_train_state(finals[-1]["path"],
-                                      p1.init_state(bundle))
-            bundle = state1.bundle
-            for k in _PHASE1_SUMMARY_KEYS:
-                if k in finals[-1]["meta"]:
-                    results[k] = finals[-1]["meta"][k]
-        else:
-            state1 = p1.init_state(bundle)
-            prior_t1 = 0.0
-            if resume_pt is not None:      # tag == "phase1": mid-phase-1
-                state1 = load_train_state(resume_pt["path"], state1)
-                # pre-interrupt wall time, so reported phase1_time stays
-                # consistent with the cumulative phase1_steps
-                prior_t1 = resume_pt["meta"].get("phase1_time", 0.0)
-            phase1_kw = dict(
-                max_steps=cfg.phase1.max_steps - int(np.asarray(state1.step)),
-                stop_accuracy=cfg.phase1.stop_accuracy,
-                log=results["phase1_log"], checkpointer=ckpt, tag="phase1",
-                checkpoint_meta=lambda tt: {
-                    "phase1_time": prior_t1 + time.perf_counter() - t0})
-            res1 = _supervised(p1.runner, state1, 0, **phase1_kw) \
-                if self.supervisor is not None \
-                else run_phase(p1.runner, state1, 0, **phase1_kw)
-            state1 = res1.state
-            bundle = state1.bundle
-            results["phase1_steps"] = int(np.asarray(state1.step))
-            results["phase1_train_acc"] = float(np.asarray(state1.acc_ema))
-            # loss-scale diagnostics (trivial — 0 skips, scale 1 — for f32)
-            results["phase1_skipped_steps"] = int(
-                np.asarray(state1.scale.skipped))
-            results["phase1_loss_scale"] = float(
-                np.asarray(state1.scale.scale))
-            results["phase1_time"] = prior_t1 + time.perf_counter() - t0
-            results["phase1_test_acc"] = adapter.eval_accuracy(
-                bundle, self.test_loader)
-            if ckpt is not None:
-                ckpt.save("phase1_final", state1,
-                          meta={k: results[k] for k in _PHASE1_SUMMARY_KEYS})
+        with obs.span("SWAP.phase1") as phase1:
+            bundle = adapter.init(key)
+            p1 = SGDRun(adapter, cfg.phase1, self.train_arrays, seed=cfg.seed,
+                        dist=self.dist)
+            if resume_pt is not None and resume_pt["tag"] in ("phase1_final",
+                                                              "phase2"):
+                # phase 1 finished in a previous process: restore its final
+                # state + summary metrics from the phase1_final snapshot
+                finals = [c for c in list_checkpoints(cfg.checkpoint_dir)
+                          if c["tag"] == "phase1_final"]
+                if not finals:
+                    raise ValueError(
+                        f"cannot resume {resume_pt['tag']} from "
+                        f"{cfg.checkpoint_dir!r}: no phase1_final snapshot")
+                state1 = load_train_state(finals[-1]["path"],
+                                          p1.init_state(bundle))
+                bundle = state1.bundle
+                for k in _PHASE1_SUMMARY_KEYS:
+                    if k in finals[-1]["meta"]:
+                        results[k] = finals[-1]["meta"][k]
+            else:
+                state1 = p1.init_state(bundle)
+                prior_t1 = 0.0
+                if resume_pt is not None:      # tag == "phase1": mid-phase-1
+                    state1 = load_train_state(resume_pt["path"], state1)
+                    # pre-interrupt wall time, so reported phase1_time stays
+                    # consistent with the cumulative phase1_steps
+                    prior_t1 = resume_pt["meta"].get("phase1_time", 0.0)
+                phase1_kw = dict(
+                    max_steps=(cfg.phase1.max_steps
+                               - int(np.asarray(state1.step))),
+                    stop_accuracy=cfg.phase1.stop_accuracy,
+                    log=results["phase1_log"], checkpointer=ckpt, tag="phase1",
+                    checkpoint_meta=lambda tt: {
+                        "phase1_time": prior_t1 + phase1.elapsed()})
+                res1 = _supervised(p1.runner, state1, 0, **phase1_kw) \
+                    if self.supervisor is not None \
+                    else run_phase(p1.runner, state1, 0, **phase1_kw)
+                state1 = res1.state
+                bundle = state1.bundle
+                results["phase1_steps"] = int(np.asarray(state1.step))
+                results["phase1_train_acc"] = float(np.asarray(state1.acc_ema))
+                # loss-scale diagnostics (trivially 0 skips, scale 1, in f32)
+                results["phase1_skipped_steps"] = int(
+                    np.asarray(state1.scale.skipped))
+                results["phase1_loss_scale"] = float(
+                    np.asarray(state1.scale.scale))
+                results["phase1_time"] = prior_t1 + phase1.elapsed()
+                results["phase1_test_acc"] = adapter.eval_accuracy(
+                    bundle, self.test_loader)
+                if ckpt is not None:
+                    ckpt.save("phase1_final", state1,
+                              meta={k: results[k]
+                                    for k in _PHASE1_SUMMARY_KEYS})
 
         # ---------------- phase 2: independent small-batch workers -------
         W = cfg.n_workers
@@ -350,18 +352,20 @@ class SWAP:
                 "phase2_train_time": prior_t2 + tt,
                 "n_workers": W},
             on_chunk=hooks)
-        if self.supervisor is not None:
-            res2 = _supervised(runner2, state2, workers,
-                               place=self._place_ensemble,
-                               chunk_filter=phase2_chunk_filter, **phase2_kw)
-            workers = res2.worker
-        elif phase2_chunk_filter is not None:
+        if self.supervisor is None and phase2_chunk_filter is not None:
             raise ValueError(
                 "phase2_chunk_filter needs a supervisor attached "
                 "(SWAP(..., supervisor=...)): without one, no guard "
                 "observes the injected fault")
-        else:
-            res2 = run_phase(runner2, state2, workers, **phase2_kw)
+        with obs.span("SWAP.phase2"):
+            if self.supervisor is not None:
+                res2 = _supervised(runner2, state2, workers,
+                                   place=self._place_ensemble,
+                                   chunk_filter=phase2_chunk_filter,
+                                   **phase2_kw)
+                workers = res2.worker
+            else:
+                res2 = run_phase(runner2, state2, workers, **phase2_kw)
         state2 = res2.state
         # surviving ensemble: the stacked leading axis after any mid-phase
         # recovery shrink, with original worker identities preserved
@@ -384,7 +388,6 @@ class SWAP:
         results["worker_test_accs"] = worker_accs
 
         # ---------------- phase 3: average + BN recompute ----------------
-        t3 = time.perf_counter()
         if self.dist.elastic:
             # deadline-gated: fold whichever workers reported in time; a
             # lost worker (arrival inf) shrinks the ensemble instead of
@@ -400,12 +403,20 @@ class SWAP:
                 # simulated arrivals are per ORIGINAL worker id; realign to
                 # the survivors' stacked positions
                 worker_arrivals = [worker_arrivals[wid] for wid in worker_ids]
-            avg_params, live_mask = elastic_average_stacked(
-                state2.bundle["params"], self.dist,
-                worker_arrivals=worker_arrivals)
-        else:
-            avg_params = average_stacked(state2.bundle["params"])
-            live_mask = np.ones(W_live, dtype=bool)
+        with obs.span("SWAP.phase3") as phase3:
+            with obs.span("SWAP.average"):
+                if self.dist.elastic:
+                    avg_params, live_mask = elastic_average_stacked(
+                        state2.bundle["params"], self.dist,
+                        worker_arrivals=worker_arrivals)
+                else:
+                    avg_params = average_stacked(state2.bundle["params"])
+                    live_mask = np.ones(W_live, dtype=bool)
+                jax.block_until_ready(avg_params)
+            with obs.span("SWAP.bn_recompute"):
+                final = adapter.finalize(avg_params, bn_loader,
+                                         cfg.bn_recompute_batches)
+        results["phase3_time"] = phase3.seconds
         # report liveness over the ORIGINAL configured ensemble: a worker
         # dropped by mid-phase recovery is dead, a surviving position maps
         # back to its identity
@@ -416,13 +427,8 @@ class SWAP:
         results["phase2_live_workers"] = int(sum(full_mask))
         live_accs = [a for a, live in zip(worker_accs, live_mask) if live]
         results["before_avg_test_acc"] = sum(live_accs) / len(live_accs)
-        final = adapter.finalize(avg_params, bn_loader,
-                                 cfg.bn_recompute_batches)
-        t4 = time.perf_counter()
-        results["phase3_time"] = t4 - t3
         results["after_avg_test_acc"] = adapter.eval_accuracy(
             final, self.test_loader)
-        results["total_time"] = t4 - t0
         results["final_bundle"] = final
         results["stacked_params"] = state2.bundle["params"]
         results["phase1_bundle"] = bundle

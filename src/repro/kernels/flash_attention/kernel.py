@@ -174,6 +174,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, Dp), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention_pallas_fwd",
     )(qf, kf, vf)
 
     out = jnp.swapaxes(out[:, :Sq, :D].reshape(B, H, Sq, D), 1, 2)
@@ -320,6 +321,7 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, block_q, Dp), q_map),
         scratch_shapes=[pltpu.VMEM((block_q, Dp), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_pallas_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     # dK/dV: grid minor dim runs over (g, qi) pairs of this kv head
@@ -358,6 +360,7 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
             pltpu.VMEM((block_k, Dp), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_pallas_bwd_dkv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     def unflat(x, S, NH):

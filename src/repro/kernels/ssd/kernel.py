@@ -204,6 +204,7 @@ def ssd_chunk_pallas_bwd(x, dt, A, Bm, Cm, dy, dstates, dcum, *,
             pl.BlockSpec((1, 1, 1, 1), lambda bh, ci: (bh, ci, 0, 0)),
         ),
         interpret=interpret,
+        name="ssd_chunk_pallas_bwd",
     )(xf, dtf, bf, cf, af, dyf, dsf, dcf)
 
     def unflat(t, extra):
@@ -263,6 +264,7 @@ def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, chunk: int = 128,
             pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
         ),
         interpret=interpret,
+        name="ssd_chunk_pallas",
     )(xf, dtf, bf, cf, af)
 
     y = jnp.swapaxes(y.reshape(Bsz, H, S, P), 1, 2)

@@ -56,5 +56,6 @@ def running_average_pallas(avg, w, n, *, interpret: bool | None = None):
         ],
         out_specs=pl.BlockSpec((1, _SUBS, _LANES), lambda i: (i, 0, 0)),
         interpret=interpret,
+        name="swa_avg_pallas",
     )(nf, ap, wp)
     return out.reshape(-1)[:size]

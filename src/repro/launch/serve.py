@@ -36,6 +36,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.checkpoint.io import load_pytree
 from repro.configs import registry
 from repro.dist.config import DistConfig, add_dist_args
@@ -100,16 +101,16 @@ def generate(model: Model, params, prompts, new_tokens: int,
     prefill = jax.jit(lambda p, t: model.prefill(p, t, cache_len=cache_len,
                                                  **extras))
 
-    t0 = time.perf_counter()
-    logits, cache = prefill(params, prompts)
-    jax.block_until_ready(logits)
-    t_prefill = time.perf_counter() - t0
+    with obs.span("generate.prefill") as span:
+        logits, cache = prefill(params, prompts)
+        jax.block_until_ready(logits)
+    t_prefill = span.seconds
 
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
     decode = _decode_compiled if engine == "compiled" else _decode_loop
-    t0 = time.perf_counter()
-    out = decode(model, params, cache, tok, S, new_tokens, greedy, rng)
-    t_decode = time.perf_counter() - t0
+    with obs.span("generate.decode") as span:
+        out = decode(model, params, cache, tok, S, new_tokens, greedy, rng)
+    t_decode = span.seconds
 
     gen = B * new_tokens
     total = t_prefill + t_decode
@@ -271,6 +272,10 @@ def main():
               f"decode_transfers={st['decode_transfers']} "
               f"publish_swaps={st['publish_swaps']} "
               f"dual_decode_calls={st['dual_decode_calls']}")
+        print(f"queue: {st['admissions']} admissions waited "
+              f"{st['queue_s']:.3f} s in all "
+              f"({1e3 * st['queue_s'] / max(st['admissions'], 1):.1f} ms "
+              f"each)")
         return
 
     B = args.batch

@@ -190,50 +190,56 @@ class Model:
         aux = jnp.zeros((), jnp.float32)
         cache = {}
         if kind.block == "mamba":
-            x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
-            if mode == "prefill":
-                y, mc = mamba2.mamba_forward(p["mamba"], x, cfg,
-                                             return_cache=True, length=length)
-                cache["m"] = mc
-            else:
-                y = mamba2.mamba_forward(p["mamba"], x, cfg)
-            return h + y, cache, aux
+            with jax.named_scope("mixer"):
+                x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
+                if mode == "prefill":
+                    y, mc = mamba2.mamba_forward(
+                        p["mamba"], x, cfg, return_cache=True, length=length)
+                    cache["m"] = mc
+                else:
+                    y = mamba2.mamba_forward(p["mamba"], x, cfg)
+                return h + y, cache, aux
 
-        x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
-        causal = not (cfg.family == "audio" and mode == "encode")
-        if cfg.attention == "mla":
-            if mode == "prefill":
-                y, ac = attn.mla_forward(p["attn"], x, cfg,
-                                         positions=positions, return_cache=True)
-                cache["a"] = ac
+        with jax.named_scope("attention"):
+            x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
+            causal = not (cfg.family == "audio" and mode == "encode")
+            if cfg.attention == "mla":
+                if mode == "prefill":
+                    y, ac = attn.mla_forward(p["attn"], x, cfg,
+                                             positions=positions,
+                                             return_cache=True)
+                    cache["a"] = ac
+                else:
+                    y = attn.mla_forward(p["attn"], x, cfg,
+                                         positions=positions)
             else:
-                y = attn.mla_forward(p["attn"], x, cfg, positions=positions)
-        else:
-            pos = positions if self.use_rope else None
-            if mode == "prefill":
-                y, ac = attn.gqa_forward(
-                    p["attn"], x, cfg, positions=pos, window=kind.window,
-                    causal=causal, return_cache=True, length=length)
-                cache["a"] = ac
-            else:
-                y = attn.gqa_forward(p["attn"], x, cfg, positions=pos,
-                                     window=kind.window, causal=causal)
-        h = h + y
-        if kind.cross and enc_out is not None:
-            x = apply_norm(p["lnx"], h, cfg.norm, cfg.norm_eps)
-            if mode == "prefill":
-                y, xc = attn.gqa_forward(p["xattn"], x, cfg, cross_x=enc_out,
-                                         return_cache=True)
-                cache["x"] = xc
-            else:
-                y = attn.gqa_forward(p["xattn"], x, cfg, cross_x=enc_out)
+                pos = positions if self.use_rope else None
+                if mode == "prefill":
+                    y, ac = attn.gqa_forward(
+                        p["attn"], x, cfg, positions=pos, window=kind.window,
+                        causal=causal, return_cache=True, length=length)
+                    cache["a"] = ac
+                else:
+                    y = attn.gqa_forward(p["attn"], x, cfg, positions=pos,
+                                         window=kind.window, causal=causal)
             h = h + y
-        x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
-        if kind.use_moe:
-            y, aux = moe.moe_forward(p["moe"], x, cfg)
-        else:
-            y = apply_mlp(p["mlp"], x, cfg.act, self.dtype)
-        return h + y, cache, aux
+            if kind.cross and enc_out is not None:
+                x = apply_norm(p["lnx"], h, cfg.norm, cfg.norm_eps)
+                if mode == "prefill":
+                    y, xc = attn.gqa_forward(p["xattn"], x, cfg,
+                                             cross_x=enc_out,
+                                             return_cache=True)
+                    cache["x"] = xc
+                else:
+                    y = attn.gqa_forward(p["xattn"], x, cfg, cross_x=enc_out)
+                h = h + y
+        with jax.named_scope("moe" if kind.use_moe else "mlp"):
+            x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
+            if kind.use_moe:
+                y, aux = moe.moe_forward(p["moe"], x, cfg)
+            else:
+                y = apply_mlp(p["mlp"], x, cfg.act, self.dtype)
+            return h + y, cache, aux
 
     # ------------------------------------------------------------------
     # block execution (decode: one token)
@@ -345,7 +351,8 @@ class Model:
         if positions is None:
             positions = self._default_positions(B, S)
         enc_out = self._encode(params, frames) if cfg.is_encoder_decoder else None
-        h = self._embed(params, tokens, positions, vision_embeds)
+        with jax.named_scope("embed"):
+            h = self._embed(params, tokens, positions, vision_embeds)
 
         def unit_body(carry, unit_p):
             h, aux = carry
@@ -360,23 +367,24 @@ class Model:
             return (h, aux), None
 
         body = self._remat(unit_body) if cfg.remat else unit_body
-        aux0 = jnp.zeros((), jnp.float32)
-        if "blocks" in params:
-            if cfg.scan_layers:
-                (h, aux), _ = jax.lax.scan(body, (h, aux0), params["blocks"])
-            else:
-                carry = (h, aux0)
-                for u in range(self.n_units):
-                    carry, _ = body(carry, _tree_index(params["blocks"], u))
-                h, aux = carry
-        else:
-            aux = aux0
-        for i, kind in enumerate(self.tail_kinds):
-            h, _, a = self._block_full(_tree_index(params["tail"], i), h,
-                                       kind, positions, "train", enc_out)
-            aux = aux + a
-        h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
-        logits = self._head(params, h)
+        aux = jnp.zeros((), jnp.float32)
+        with jax.named_scope("layers"):
+            if "blocks" in params:
+                if cfg.scan_layers:
+                    (h, aux), _ = jax.lax.scan(body, (h, aux),
+                                               params["blocks"])
+                else:
+                    for u in range(self.n_units):
+                        (h, aux), _ = body(
+                            (h, aux), _tree_index(params["blocks"], u))
+            for i, kind in enumerate(self.tail_kinds):
+                h, _, a = self._block_full(_tree_index(params["tail"], i),
+                                           h, kind, positions, "train",
+                                           enc_out)
+                aux = aux + a
+        with jax.named_scope("lm_head"):
+            h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
+            logits = self._head(params, h)
         return logits, aux
 
     def _head(self, params, h):

@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.dist.sharding import (batch_shardings, cache_batch_dim,
                                  cache_shardings, page_pool_dim,
                                  param_shardings, path_str)
@@ -252,10 +253,12 @@ class CompiledServingEngine:
         # instrumentation consumed by benchmarks/bench_serve.py and
         # bench_publish.py: the zero-per-token-round-trip claim is
         # `decode_transfers == decode_calls` (one bulk block fetch per
-        # fused call) — publishes must not add host syncs
-        self.stats: Dict[str, int] = {
+        # fused call) — publishes must not add host syncs. ``queue_s`` is
+        # the admitted requests' summed wait from submit to admission, on
+        # the engine's clock; ``admissions`` counts them.
+        self.stats: Dict[str, float] = {
             "decode_calls": 0, "decode_transfers": 0, "decode_steps": 0,
-            "admissions": 0, "admit_transfers": 0, "prefill_compiles": 0,
+            "admissions": 0, "queue_s": 0.0, "prefill_compiles": 0,
             "publishes": 0, "publish_swaps": 0, "publish_superseded": 0,
             "dual_decode_calls": 0, "admit_page_waits": 0, "rejections": 0,
         }
@@ -595,10 +598,18 @@ class CompiledServingEngine:
                     return
             slot = free[0]
             req = self.waiting.pop(0)
-            S = req.prompt.shape[0]
-            bucket = self._bucket(S)
-            padded = jnp.pad(req.prompt[None, :].astype(jnp.int32),
-                             ((0, 0), (0, bucket - S)))
+            with obs.span("serve.admit"):
+                self._admit_one(req, slot, full_blocks)
+
+    def _admit_one(self, req: Request, slot: int, full_blocks: int) -> None:
+        """Prefill ``req`` into ``slot`` and seat it for decoding."""
+        self.stats["admissions"] += 1
+        self.stats["queue_s"] += float(self._clock()) - req.submit_t
+        S = req.prompt.shape[0]
+        bucket = self._bucket(S)
+        padded = jnp.pad(req.prompt[None, :].astype(jnp.int32),
+                         ((0, 0), (0, bucket - S)))
+        with obs.span("serve.prefill"):
             logits, pc = self._run_prefill(bucket, padded, S)
             if self.sample == "greedy":
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)[0]
@@ -608,33 +619,31 @@ class CompiledServingEngine:
                     key, logits.astype(jnp.float32)
                     / self.temperature, axis=-1).astype(jnp.int32)[0]
             t0 = int(tok)                         # one scalar per ADMISSION
-            self.stats["admissions"] += 1
-            self.stats["admit_transfers"] += 1
-            req.generated = [t0]
-            req.generation = self.generation      # pinned for its lifetime
-            done0 = (req.max_new_tokens <= 1
-                     or (req.eos_id is not None and t0 == req.eos_id)
-                     or S >= self.max_seq - 1)
-            page_row = np.zeros((self._n_blocks,), np.int32)
-            if self._paged and not done0:
-                # allocate only the PROMPT's pages now (rows 0..S — the
-                # prompt plus the first decode write); growth happens
-                # lazily in _ensure_pages as the request decodes
-                self.slot_max_blocks[slot] = full_blocks
-                self._alloc_slot_pages(
-                    slot, min(S // self.page_size + 1, full_blocks))
-                page_row = self._host_bt[slot].copy()
-            self.state = self._admit_fn(
-                self.state, pc, tok, jnp.int32(slot), jnp.int32(S),
-                jnp.int32(req.max_new_tokens - 1), jnp.int32(
-                    -1 if req.eos_id is None else req.eos_id),
-                jnp.asarray(not done0), jnp.asarray(page_row))
-            if done0:
-                req.done = True
-            else:
-                self.slot_req[slot] = req
-                self.slot_len[slot] = S
-                self.slot_buf[slot] = self._latest
+        req.generated = [t0]
+        req.generation = self.generation          # pinned for its lifetime
+        done0 = (req.max_new_tokens <= 1
+                 or (req.eos_id is not None and t0 == req.eos_id)
+                 or S >= self.max_seq - 1)
+        page_row = np.zeros((self._n_blocks,), np.int32)
+        if self._paged and not done0:
+            # allocate only the PROMPT's pages now (rows 0..S — the
+            # prompt plus the first decode write); growth happens
+            # lazily in _ensure_pages as the request decodes
+            self.slot_max_blocks[slot] = full_blocks
+            self._alloc_slot_pages(
+                slot, min(S // self.page_size + 1, full_blocks))
+            page_row = self._host_bt[slot].copy()
+        self.state = self._admit_fn(
+            self.state, pc, tok, jnp.int32(slot), jnp.int32(S),
+            jnp.int32(req.max_new_tokens - 1), jnp.int32(
+                -1 if req.eos_id is None else req.eos_id),
+            jnp.asarray(not done0), jnp.asarray(page_row))
+        if done0:
+            req.done = True
+        else:
+            self.slot_req[slot] = req
+            self.slot_len[slot] = S
+            self.slot_buf[slot] = self._latest
 
     def _split_host_key(self):
         rng, key = jax.random.split(self.state.rng)
@@ -683,26 +692,28 @@ class CompiledServingEngine:
         if any(r is not None and self.slot_buf[i] == target
                for i, r in enumerate(self.slot_req)):
             return False                          # deferred: buffer busy
-        gen, params = self._pending
-        ref = self._buffers[self._latest]
+        with obs.span("serve.publish_swap"):
+            gen, params = self._pending
+            ref = self._buffers[self._latest]
 
-        def place(new, old):
-            new = jnp.asarray(new, getattr(old, "dtype", None))
-            if new.shape != old.shape:
-                raise ValueError(
-                    f"published params have leaf shape {new.shape} where "
-                    f"the engine expects {old.shape} — generation "
-                    f"published from a different model config?")
-            return new
+            def place(new, old):
+                new = jnp.asarray(new, getattr(old, "dtype", None))
+                if new.shape != old.shape:
+                    raise ValueError(
+                        f"published params have leaf shape {new.shape} "
+                        f"where the engine expects {old.shape} — "
+                        f"generation published from a different model "
+                        f"config?")
+                return new
 
-        # cast to the resident dtypes/shapes so the compiled decode
-        # programs are reused as-is (a publish must never recompile)
-        placed = jax.tree_util.tree_map(place, params, ref)
-        if self.mesh is not None:
-            # re-pin to the serving mesh: the cast above does not carry the
-            # resident buffer's sharding over to the new generation
-            placed = jax.device_put(placed,
-                                    param_shardings(self.mesh, placed))
+            # cast to the resident dtypes/shapes so the compiled decode
+            # programs are reused as-is (a publish must never recompile)
+            placed = jax.tree_util.tree_map(place, params, ref)
+            if self.mesh is not None:
+                # re-pin to the serving mesh: the cast above does not carry
+                # the resident buffer's sharding over to the new generation
+                placed = jax.device_put(placed,
+                                        param_shardings(self.mesh, placed))
         self._buffers[target] = placed
         self._buf_gen[target] = gen
         self._latest = target
@@ -736,24 +747,32 @@ class CompiledServingEngine:
             self._push_block_tables()
         bufs = {self.slot_buf[i] for i, r in enumerate(self.slot_req)
                 if r is not None}
-        if len(bufs) == 1:
-            self.state, block = self._decode_fn(
-                self._buffers[bufs.pop()], self.state)
-        else:
-            use_b = jnp.asarray(
-                [b == 1 for b in self.slot_buf])       # async, tiny, h->d
-            use_b_pages = np.zeros((max(self.n_pages, 1),), bool)
-            for i, r in enumerate(self.slot_req):
-                if r is not None and self.slot_buf[i] == 1:
-                    use_b_pages[self.slot_pages[i]] = True
-            self.state, block = self._decode_dual_fn(
-                self._buffers[0], self._buffers[1], self.state, use_b,
-                jnp.asarray(use_b_pages))
-            self.stats["dual_decode_calls"] += 1
-        self.stats["decode_calls"] += 1
-        self.stats["decode_steps"] += self.decode_block
-        block = np.asarray(block)                 # ONE (B, K) transfer
-        self.stats["decode_transfers"] += 1
+        with obs.span("serve.decode"):
+            if len(bufs) == 1:
+                self.state, block = self._decode_fn(
+                    self._buffers[bufs.pop()], self.state)
+            else:
+                use_b = jnp.asarray(
+                    [b == 1 for b in self.slot_buf])   # async, tiny, h->d
+                use_b_pages = np.zeros((max(self.n_pages, 1),), bool)
+                for i, r in enumerate(self.slot_req):
+                    if r is not None and self.slot_buf[i] == 1:
+                        use_b_pages[self.slot_pages[i]] = True
+                self.state, block = self._decode_dual_fn(
+                    self._buffers[0], self._buffers[1], self.state, use_b,
+                    jnp.asarray(use_b_pages))
+                self.stats["dual_decode_calls"] += 1
+            self.stats["decode_calls"] += 1
+            self.stats["decode_steps"] += self.decode_block
+            block = np.asarray(block)             # ONE (B, K) transfer
+            self.stats["decode_transfers"] += 1
+        with obs.span("serve.replay"):
+            self._replay(block)
+        self._admit()
+
+    def _replay(self, block) -> None:
+        """Append each slot's tokens of the fused call's ``(B, K)`` block
+        and apply the device's stop rule on the host."""
         for slot, req in enumerate(self.slot_req):
             if req is None:
                 continue
@@ -768,7 +787,6 @@ class CompiledServingEngine:
                     req.done = True
                     self._release_slot(slot)      # pages return to the pool
                     break
-        self._admit()
 
     def run(self, requests: List[Request], max_steps: int = 10_000
             ) -> Dict[int, List[int]]:

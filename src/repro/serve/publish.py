@@ -32,6 +32,7 @@ import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.checkpoint.state import (
     find_latest_publish, load_publish, save_publish, state_step,
 )
@@ -137,26 +138,27 @@ class WeightPublisher:
         Retries re-run the whole attempt (snapshot + delivery) under the
         SAME generation number — ``save_publish`` is an atomic overwrite,
         so a half-delivered retry can never fork generation history."""
-        attempt = 0
-        while True:
-            try:
-                return self._publish_once(params, step)
-            except _RETRYABLE as err:
-                attempt += 1
-                if attempt <= self.max_retries:
-                    self._sleep(self.retry_backoff_s * 2 ** (attempt - 1))
-                    continue
-                if self.on_failure == "raise":
-                    raise
-                self.failures.append(
-                    {"step": step, "attempts": attempt,
-                     "error": f"{type(err).__name__}: {err}"})
-                warnings.warn(
-                    f"publish at step {step} failed after {attempt} "
-                    f"attempt(s) ({err}); skipping — the next epoch "
-                    f"boundary publishes a fresher average",
-                    RuntimeWarning)
-                return self.generation
+        with obs.span("WeightPublisher.publish"):
+            attempt = 0
+            while True:
+                try:
+                    return self._publish_once(params, step)
+                except _RETRYABLE as err:
+                    attempt += 1
+                    if attempt <= self.max_retries:
+                        self._sleep(self.retry_backoff_s * 2 ** (attempt - 1))
+                        continue
+                    if self.on_failure == "raise":
+                        raise
+                    self.failures.append(
+                        {"step": step, "attempts": attempt,
+                         "error": f"{type(err).__name__}: {err}"})
+                    warnings.warn(
+                        f"publish at step {step} failed after {attempt} "
+                        f"attempt(s) ({err}); skipping — the next epoch "
+                        f"boundary publishes a fresher average",
+                        RuntimeWarning)
+                    return self.generation
 
     def _publish_once(self, params, step: int) -> int:
         gen = self.generation + 1

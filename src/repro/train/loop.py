@@ -40,13 +40,13 @@ so a phase compiles at most two programs per runner.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.data.pipeline import Loader
 from repro.train.precision import (
     LossScaleState, default_scale_state, stack_scale_state,
@@ -215,6 +215,7 @@ class EpochRunner:
         if fn is not None:
             return fn
         _require_auto_inputs(state, worker)
+        obs.count("EpochRunner.programs")
         step_fn, loader, beta = self.step_fn, self.loader, self.ema_beta
 
         def run_chunk(state: TrainState, worker):
@@ -293,17 +294,21 @@ class EpochRunner:
         worker is repeated up to the next multiple, so each worker block
         still trains only its own share; the copies are dropped afterwards
         and the state goes back to ``ensemble_shardings`` of the W workers
-        (replicated on the worker axis, since W does not divide it)."""
-        pad = self._worker_pad(worker)
-        if not pad:
-            return self._chunk_fn(n_steps, state, worker)(state, worker)
-        n = worker.shape[0]
-        padded = _resize_rows(self.mesh, (state, worker), n + pad)
-        if self.donate:  # free the caller's copy before the chunk runs
-            jax.tree_util.tree_map(lambda a: a.delete(), state)
-        state, metrics = self._chunk_fn(n_steps, *padded)(*padded)
-        return (_resize_rows(self.mesh, state, n),
-                jax.tree_util.tree_map(lambda a: a[:n], metrics))
+        (replicated on the worker axis, since W does not divide it).
+
+        The span ``EpochRunner.run_chunk`` times the host's part of the
+        call: dispatch returns before the device has run the chunk."""
+        with obs.span("EpochRunner.run_chunk"):
+            pad = self._worker_pad(worker)
+            if not pad:
+                return self._chunk_fn(n_steps, state, worker)(state, worker)
+            n = worker.shape[0]
+            padded = _resize_rows(self.mesh, (state, worker), n + pad)
+            if self.donate:  # free the caller's copy before the chunk runs
+                jax.tree_util.tree_map(lambda a: a.delete(), state)
+            state, metrics = self._chunk_fn(n_steps, *padded)(*padded)
+            return (_resize_rows(self.mesh, state, n),
+                    jax.tree_util.tree_map(lambda a: a[:n], metrics))
 
     def lower_chunk(self, state, worker, n_steps: int):
         """AOT-lower one chunk without executing it (``state``/``worker``
@@ -391,23 +396,23 @@ def run_phase(runner: EpochRunner, state: TrainState, worker, *,
     first = chunk - offset if offset else chunk
     while done < max_steps:
         n = min(first if done == 0 else chunk, max_steps - done)
-        t0 = time.perf_counter()
-        state, metrics = runner.run_chunk(state, worker, n)
-        jax.block_until_ready(state.bundle)
-        train_time += time.perf_counter() - t0
+        with obs.span("run_phase.chunk") as span:
+            state, metrics = runner.run_chunk(state, worker, n)
+            jax.block_until_ready(state.bundle)
+        train_time += span.seconds
         done += n
 
-        t1 = time.perf_counter()
-        if log is not None:
-            start = int(np.asarray(state.step).reshape(-1)[0]) - n
-            _append_log(log, metrics, start)
-        for hook in hooks:
-            hook(state, done)
-        if checkpointer is not None:
-            checkpointer.maybe_save(
-                tag, state,
-                checkpoint_meta(train_time) if checkpoint_meta else None)
-        hook_time += time.perf_counter() - t1
+        with obs.span("run_phase.hooks") as span:
+            if log is not None:
+                start = int(np.asarray(state.step).reshape(-1)[0]) - n
+                _append_log(log, metrics, start)
+            for hook in hooks:
+                hook(state, done)
+            if checkpointer is not None:
+                checkpointer.maybe_save(
+                    tag, state,
+                    checkpoint_meta(train_time) if checkpoint_meta else None)
+        hook_time += span.seconds
 
         if stop_accuracy is not None and _ema_value(state) >= stop_accuracy:
             break

@@ -261,20 +261,21 @@ def make_precision_train_step(loss_with_aux: Callable, opt_update: Callable,
         if grad_dtype != jnp.float32:
             grads = tree_map(lambda g: g.astype(grad_dtype), grads)
 
-        lr = schedule_fn(step)
-        new_params, new_opt = opt_update(grads, opt_state, params, lr)
-        if policy.dynamic:
-            finite = all_finite(grads)
-            keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
-            new_params = tree_map(keep, new_params, params)
-            new_opt = tree_map(keep, new_opt, opt_state)
-            new_mstate = tree_map(keep, new_mstate, mstate)
-            new_scale = policy.update_scale(scale_state, finite)
-            metrics = dict(metrics,
-                           skipped=1.0 - finite.astype(jnp.float32),
-                           loss_scale=scale)
-        else:
-            new_scale = scale_state
+        with jax.named_scope("optimizer"):
+            lr = schedule_fn(step)
+            new_params, new_opt = opt_update(grads, opt_state, params, lr)
+            if policy.dynamic:
+                finite = all_finite(grads)
+                keep = lambda n, o: jnp.where(finite, n, o)  # noqa: E731
+                new_params = tree_map(keep, new_params, params)
+                new_opt = tree_map(keep, new_opt, opt_state)
+                new_mstate = tree_map(keep, new_mstate, mstate)
+                new_scale = policy.update_scale(scale_state, finite)
+                metrics = dict(metrics,
+                               skipped=1.0 - finite.astype(jnp.float32),
+                               loss_scale=scale)
+            else:
+                new_scale = scale_state
         return ({"params": new_params, "state": new_mstate}, new_opt,
                 new_scale, dict(metrics, lr=lr))
 

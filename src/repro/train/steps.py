@@ -27,23 +27,24 @@ def lm_loss_and_metrics(model: Model, params, batch: Dict):
         params, batch["tokens"],
         vision_embeds=batch.get("vision_embeds"),
         frames=batch.get("frames"))
-    labels = batch["labels"]
-    # CE without take_along_axis / full f32 logits: gathers over a
-    # vocab-sharded logits tensor force GSPMD all-gathers (§Perf iter 1);
-    # the masked reduction keeps every op vocab-shardable.
-    logits_f = logits.astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(logits_f, axis=-1, keepdims=True))
-    shifted = logits_f - m
-    logz = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-    V = logits.shape[-1]
-    label_mask = (jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                           logits.ndim - 1)
-                  == labels[..., None])
-    l_y = jnp.sum(jnp.where(label_mask, shifted, 0.0), axis=-1)
-    nll = logz - l_y
-    loss = jnp.mean(nll)
-    acc = jnp.mean((jnp.argmax(logits_f, axis=-1) == labels)
-                   .astype(jnp.float32))
+    with jax.named_scope("lm_head"):
+        labels = batch["labels"]
+        # CE without take_along_axis / full f32 logits: gathers over a
+        # vocab-sharded logits tensor force GSPMD all-gathers (§Perf iter 1);
+        # the masked reduction keeps every op vocab-shardable.
+        logits_f = logits.astype(jnp.float32)
+        m = jax.lax.stop_gradient(jnp.max(logits_f, axis=-1, keepdims=True))
+        shifted = logits_f - m
+        logz = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+        V = logits.shape[-1]
+        label_mask = (jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                               logits.ndim - 1)
+                      == labels[..., None])
+        l_y = jnp.sum(jnp.where(label_mask, shifted, 0.0), axis=-1)
+        nll = logz - l_y
+        loss = jnp.mean(nll)
+        acc = jnp.mean((jnp.argmax(logits_f, axis=-1) == labels)
+                       .astype(jnp.float32))
     total = loss + aux
     return total, {"loss": loss, "aux": aux, "accuracy": acc}
 

@@ -498,3 +498,42 @@ def test_paged_rejects_unfittable_request():
     with pytest.raises(ValueError, match="pages"):
         engine.submit(Request(rid=0, prompt=_prompts(cfg, [17])[0],
                               max_new_tokens=40))
+
+
+def test_queue_time_from_submit_to_admission():
+    """``stats['queue_s']`` sums, over admitted requests, the wait from
+    ``submit`` to admission on the engine's clock; ``admissions`` counts
+    them. The engine's spans record the admission, prefill, decode call
+    and replay."""
+    from repro import obs
+
+    class Clock:
+        now = 100.0
+
+        def __call__(self):
+            return self.now
+
+    cfg, model, params = _setup("internlm2-1.8b")
+    clock = Clock()
+    engine = CompiledServingEngine(model, params, max_batch=1, max_seq=64,
+                                   decode_block=4, clock=clock)
+    first, second = (Request(rid=i, prompt=p, max_new_tokens=4)
+                     for i, p in enumerate(_prompts(cfg, [6, 7])))
+    obs.reset()
+    engine.submit(first)                   # a free slot: admitted at once
+    engine.submit(second)                  # waits for the only slot
+    assert engine.stats["admissions"] == 1
+    assert engine.stats["queue_s"] == 0.0
+    clock.now += 2.5
+    while engine.active or engine.waiting:
+        engine.step()
+    assert engine.stats["admissions"] == 2
+    assert engine.stats["queue_s"] == 2.5
+    assert obs.stats("serve.admit")["count"] == 2
+    assert obs.stats("serve.prefill")["count"] == 2
+    calls = engine.stats["decode_calls"]
+    assert obs.stats("serve.decode")["count"] == calls > 0
+    assert obs.stats("serve.replay")["count"] == calls
+    assert engine.publish(params) is True  # no request pins the other buffer
+    assert obs.stats("serve.publish_swap")["count"] == 1
+    obs.reset()
