@@ -10,8 +10,14 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
     exactly the flash streaming pattern — no atomics / warp shuffles needed).
   * GQA is handled by the K/V index_map (query head h reads kv head h//G);
     no materialized head repetition in HBM.
-  * Causal/sliding-window masking is applied with absolute-position iota
-    comparison inside the block. Fully-masked blocks contribute zeros.
+  * Tile schedule (``_Tiles``): each (q tile, k tile) pair is dead (every
+    position masked by causality or the sliding window), full (no
+    position masked) or partial. A dead pair does no work and fetches
+    nothing: its body sits under ``pl.when`` and the index map of the
+    streamed operand repeats the nearest live block, so the pipeline skips
+    the copy. A full pair skips the iota mask; a partial pair applies it
+    with absolute-position comparison. Skipping a dead pair is exact: it
+    would add p = 0 and rescale by 1.
   * Backward is flash-attention-2 style: the forward emits LSE; a dQ
     kernel accumulates over KV blocks, and a dK/dV kernel accumulates over
     (query-head-in-group x q-block) pairs via its minor grid dimension —
@@ -19,10 +25,12 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -31,9 +39,129 @@ from repro.kernels import dispatch
 NEG_INF = -1e30
 
 
+def _min(a, b):
+    return jnp.minimum(a, b) if isinstance(a, jax.Array) else np.minimum(a, b)
+
+
+def _max(a, b):
+    return jnp.maximum(a, b) if isinstance(a, jax.Array) else np.maximum(a, b)
+
+
+def _div(a, b):
+    """a // b for a >= 0: traced grid indices or numpy arrays."""
+    return jax.lax.div(a, b) if isinstance(a, jax.Array) else a // b
+
+
+@dataclasses.dataclass(frozen=True)
+class _Tiles:
+    """Static geometry of one call's (q tile, k tile) grid: which pairs
+    hold unmasked positions, and the span of live tiles of a row or a
+    column. Query row r sits at absolute position r + q_offset; rows at or
+    past q_len and keys at or past kv_len are padding, masked in the tile
+    that holds them. Methods take grid indices, traced (inside the kernel
+    and its index maps) or numpy."""
+
+    block_q: int
+    block_k: int
+    q_offset: int
+    q_len: int
+    kv_len: int
+    causal: bool
+    window: int
+
+    @property
+    def nq(self) -> int:
+        return -(-self.q_len // self.block_q)
+
+    @property
+    def nk(self) -> int:
+        return -(-self.kv_len // self.block_k)
+
+    def _q_range(self, qi):
+        """First and last absolute position of the tile's real rows."""
+        end = _min((qi + 1) * self.block_q, self.q_len)
+        return qi * self.block_q + self.q_offset, end - 1 + self.q_offset
+
+    def _k_range(self, ki):
+        end = _min((ki + 1) * self.block_k, self.kv_len)
+        return ki * self.block_k, end - 1
+
+    def pair(self, qi, ki):
+        """(live, full): some position of the pair is unmasked / none is.
+        Every tile holds a real row or key (the grid is the lengths
+        rounded up to a tile), so only the mask can kill a pair."""
+        q_lo, q_hi = self._q_range(qi)
+        k_lo, k_hi = self._k_range(ki)
+        live = True
+        full = (((qi + 1) * self.block_q <= self.q_len)
+                & ((ki + 1) * self.block_k <= self.kv_len))
+        if self.causal:
+            live &= k_lo <= q_hi
+            full &= k_hi <= q_lo
+        if self.window > 0:
+            live &= k_hi > q_lo - self.window
+            full &= k_lo > q_hi - self.window
+        return live, full
+
+    def k_span(self, qi):
+        """(first, last) live k tile of q tile ``qi``, within [0, nk)."""
+        q_lo, q_hi = self._q_range(qi)
+        first, last = 0, self.nk - 1
+        if self.window > 0:
+            first = _min(_div(_max(q_lo - self.window + 1, 0), self.block_k),
+                         last)
+        if self.causal:
+            last = _div(_min(q_hi, self.kv_len - 1), self.block_k)
+        return first, last
+
+    def q_span(self, ki):
+        """(first, last) live q tile of k tile ``ki``, within [0, nq)."""
+        k_lo, k_hi = self._k_range(ki)
+        first, last = 0, self.nq - 1
+        if self.causal:
+            first = _min(_div(_max(k_lo - self.q_offset, 0), self.block_q),
+                         last)
+        if self.window > 0:
+            row = _min(k_hi + self.window - 1 - self.q_offset, self.q_len - 1)
+            last = _div(_max(row, 0), self.block_q)
+        return first, last
+
+    def mask(self, qi, ki):
+        """The (block_q, block_k) mask of a partial pair."""
+        iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                                 (self.block_q, self.block_k))
+        row = qi * self.block_q + iota(0)
+        kpos = ki * self.block_k + iota(1)
+        qpos = row + self.q_offset
+        m = (kpos < self.kv_len) & (row < self.q_len)
+        if self.causal:
+            m &= kpos <= qpos
+        if self.window > 0:
+            m &= kpos > qpos - self.window
+        return m
+
+
+def _clip(i, span):
+    first, last = span
+    return jnp.minimum(jnp.maximum(i, first), last)
+
+
+def _by_class(tiles, qi, ki, body):
+    """Run ``body(mask)`` on live pairs: with the pair's mask on partial
+    ones, with ``None`` on full ones; dead pairs run nothing."""
+    live, full = tiles.pair(qi, ki)
+
+    @pl.when(full)
+    def _():
+        body(None)
+
+    @pl.when(live & jnp.logical_not(full))
+    def _():
+        body(tiles.mask(qi, ki))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-               scale: float, causal: bool, window: int, block_q: int,
-               block_k: int, q_offset: int, kv_len: int):
+               scale: float, tiles: _Tiles):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -43,33 +171,29 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
-    k = k_ref[0].astype(jnp.float32)                  # (bk, D)
-    v = v_ref[0].astype(jnp.float32)                  # (bk, D)
+    def step(mask):
+        q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
+        k = k_ref[0].astype(jnp.float32)                  # (bk, D)
+        v = v_ref[0].astype(jnp.float32)                  # (bk, D)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (bq, bk)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (bq, bk)
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
 
-    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_offset
-    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    mask = kpos < kv_len                               # padding mask
-    if causal:
-        mask &= kpos <= qpos
-    if window > 0:
-        mask &= kpos > qpos - window
-    s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]                                # (bq, 1)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        # fully-masked rows: m_new stays NEG_INF -> p would be exp(0)=1
+        p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+        alpha = jnp.where(m_prev > NEG_INF / 2, alpha, 0.0)
 
-    m_prev = m_ref[...]                                # (bq, 1)
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    # fully-masked rows: m_new stays NEG_INF -> p would be exp(0)=1; zero them
-    p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-    alpha = jnp.where(m_prev > NEG_INF / 2, alpha, 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
+        m_ref[...] = m_new
 
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(p, v)
-    m_ref[...] = m_new
+    _by_class(tiles, qi, ki, step)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
@@ -83,13 +207,30 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                                   m_ref[:, 0] + jnp.log(l[:, 0]))
 
 
+def _blocks(Sq, Skv, block_q, block_k):
+    """Tile sizes as the kernel runs them: no larger than the sequence."""
+    return min(block_q, max(Sq, 8)), min(block_k, max(Skv, 8))
+
+
+def tile_schedule(Sq: int, Skv: int, *, causal: bool = True, window: int = 0,
+                  q_offset: int = 0, block_q: int = 128,
+                  block_k: int = 128) -> tuple:
+    """(pairs, live pairs) of one head's (q tile, k tile) grid, as
+    ``flash_attention_pallas_fwd`` runs it with these arguments."""
+    bq, bk = _blocks(Sq, Skv, block_q, block_k)
+    tiles = _Tiles(bq, bk, q_offset, Sq, Skv, causal, window)
+    live, _ = tiles.pair(np.arange(tiles.nq)[:, None],
+                         np.arange(tiles.nk)[None, :])
+    live = np.broadcast_to(live, (tiles.nq, tiles.nk))
+    return tiles.nq * tiles.nk, int(live.sum())
+
+
 def _layout(q, k, v, block_q, block_k, interpret):
     """Flatten to (B*H, S, D) batch-head major, pad to block/lane multiples."""
     B, Sq, H, D = q.shape
     _, Skv, KVH, _ = k.shape
     Dp = max(128, (D + 127) // 128 * 128) if not interpret else D
-    block_q = min(block_q, max(Sq, 8))
-    block_k = min(block_k, max(Skv, 8))
+    block_q, block_k = _blocks(Sq, Skv, block_q, block_k)
     Sqp = (Sq + block_q - 1) // block_q * block_q
     Skvp = (Skv + block_k - 1) // block_k * block_k
 
@@ -138,7 +279,8 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
     scale = scale if scale is not None else D ** -0.5
     qf, kf, vf, Dp, block_q, block_k, Sqp, Skvp = _layout(
         q, k, v, block_q, block_k, interpret)
-    grid = (B * H, Sqp // block_q, Skvp // block_k)
+    tiles = _Tiles(block_q, block_k, q_offset, Sq, Skv, causal, window)
+    grid = (B * H, tiles.nq, tiles.nk)
 
     def q_map(bh, qi, ki):
         return (bh, qi, 0)
@@ -148,12 +290,10 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
 
     def kv_map(bh, qi, ki):
         b, h = bh // H, bh % H
-        return (b * KVH + h // G, ki, 0)
+        return (b * KVH + h // G, _clip(ki, tiles.k_span(qi)), 0)
 
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fa_kernel, scale=scale, causal=causal, window=window,
-            block_q=block_q, block_k=block_k, q_offset=q_offset, kv_len=Skv),
+        functools.partial(_fa_kernel, scale=scale, tiles=tiles),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
             jax.ShapeDtypeStruct((B * H, 1, Sqp), jnp.float32),
@@ -187,43 +327,31 @@ def flash_attention_pallas_fwd(q, k, v, *, causal: bool = True,
 # ---------------------------------------------------------------------------
 
 
-def _mask(qi, ki, block_q, block_k, q_offset, q_len, kv_len, causal, window):
-    qpos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) + q_offset
-    kpos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    m = (kpos < kv_len) & (qpos - q_offset < q_len)
-    if causal:
-        m &= kpos <= qpos
-    if window > 0:
-        m &= kpos > qpos - window
-    return m
-
-
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, acc_ref, *, scale, causal, window, block_q,
-                      block_k, q_offset, q_len, kv_len):
+                      dq_ref, acc_ref, *, scale, tiles: _Tiles):
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                              # (block_q,)
-    delta = delta_ref[0, 0]                          # (block_q,)
+    def step(mask):
+        q = q_ref[0].astype(jnp.float32) * scale
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0, 0]                              # (block_q,)
+        delta = delta_ref[0, 0]                          # (block_q,)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
-    mask = _mask(qi, ki, block_q, block_k, q_offset, q_len, kv_len, causal,
-                 window)
-    s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                    # (bq, bk)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None])
-    acc_ref[...] += jax.lax.dot(ds, k) * scale
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[:, None])                    # (bq, bk)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
+        ds = p * (dp - delta[:, None])
+        acc_ref[...] += jax.lax.dot(ds, k) * scale
+
+    _by_class(tiles, qi, ki, step)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _():
@@ -231,33 +359,34 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                       window, block_q, block_k, q_offset, q_len, kv_len,
-                       nq: int):
+                       dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
+                       tiles: _Tiles):
     ki, gq = pl.program_id(1), pl.program_id(2)
-    qi = gq % nq
+    qi = gq % tiles.nq
 
     @pl.when(gq == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    def step(mask):
+        q = q_ref[0].astype(jnp.float32) * scale
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
-    mask = _mask(qi, ki, block_q, block_k, q_offset, q_len, kv_len, causal,
-                 window)
-    s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                    # (bq, bk)
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-    ds = p * (dp - delta[:, None])                   # (bq, bk)
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[:, None])                    # (bq, bk)
+        dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
+        ds = p * (dp - delta[:, None])                   # (bq, bk)
+        dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+
+    _by_class(tiles, qi, ki, step)
 
     @pl.when(gq == pl.num_programs(2) - 1)
     def _():
@@ -291,10 +420,8 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
                      ((0, 0), (0, 0), (0, Sqp - Sq)))
     lsef = jnp.pad(jnp.swapaxes(lse, 1, 2).reshape(B * H, 1, Sq),
                    ((0, 0), (0, 0), (0, Sqp - Sq)))
-    nq, nk = Sqp // block_q, Skvp // block_k
-
-    kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
-              block_k=block_k, q_offset=q_offset, q_len=Sq, kv_len=Skv)
+    tiles = _Tiles(block_q, block_k, q_offset, Sq, Skv, causal, window)
+    nq, nk = tiles.nq, tiles.nk
 
     def q_map(bh, qi, ki):
         return (bh, qi, 0)
@@ -304,10 +431,10 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
 
     def kv_map(bh, qi, ki):
         b, h = bh // H, bh % H
-        return (b * KVH + h // G, ki, 0)
+        return (b * KVH + h // G, _clip(ki, tiles.k_span(qi)), 0)
 
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, **kw),
+        functools.partial(_fa_bwd_dq_kernel, scale=scale, tiles=tiles),
         out_shape=jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
         grid=(B * H, nq, nk),
         in_specs=[
@@ -324,20 +451,26 @@ def flash_attention_pallas_bwd(q, k, v, out, lse, do, *, causal: bool = True,
         name="flash_attention_pallas_bwd_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
-    # dK/dV: grid minor dim runs over (g, qi) pairs of this kv head
-    def q_map2(bkv, ki, gq):
+    # dK/dV: grid minor dim runs over (g, qi) pairs of this kv head; a dead
+    # pair's q-side operands repeat the nearest live q tile of this head
+    def head_and_q(bkv, ki, gq):
         b, hkv = bkv // KVH, bkv % KVH
-        return (b * H + hkv * G + gq // nq, gq % nq, 0)
+        qi = _clip(gq % nq, tiles.q_span(ki))
+        return b * H + hkv * G + gq // nq, qi
+
+    def q_map2(bkv, ki, gq):
+        bh, qi = head_and_q(bkv, ki, gq)
+        return (bh, qi, 0)
 
     def r_map2(bkv, ki, gq):
-        b, hkv = bkv // KVH, bkv % KVH
-        return (b * H + hkv * G + gq // nq, 0, gq % nq)
+        bh, qi = head_and_q(bkv, ki, gq)
+        return (bh, 0, qi)
 
     def kv_map2(bkv, ki, gq):
         return (bkv, ki, 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, **kw, nq=nq),
+        functools.partial(_fa_bwd_dkv_kernel, scale=scale, tiles=tiles),
         out_shape=(
             jax.ShapeDtypeStruct((B * KVH, Skvp, Dp), k.dtype),
             jax.ShapeDtypeStruct((B * KVH, Skvp, Dp), v.dtype),

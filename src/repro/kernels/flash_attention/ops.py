@@ -18,7 +18,8 @@ kernels with the forward's LSE.
 TPU, compiled Triton on GPU, the blockwise reference on CPU
 (repro.kernels.dispatch); the resolved design point (block sizes,
 num_warps/num_stages) comes from the persisted tuning cache, or from the
-``design`` argument when a caller pins one.
+``design`` argument when a caller pins one; a Mosaic call on the TPU with
+neither takes the tile measured on the chip (``_mosaic_blocks``).
 """
 from __future__ import annotations
 
@@ -27,17 +28,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import dispatch
 from repro.kernels.flash_attention import ref as _ref
 from repro.kernels.flash_attention.kernel import (
     flash_attention_pallas, flash_attention_pallas_bwd,
-    flash_attention_pallas_fwd,
+    flash_attention_pallas_fwd, tile_schedule,
 )
 from repro.kernels.flash_attention.kernel_gpu import (
     flash_attention_triton, flash_attention_triton_bwd,
     flash_attention_triton_fwd,
 )
-from repro.kernels.tuning import DEFAULT_DESIGN
+from repro.kernels.tuning import (DEFAULT_DESIGN, TPU_FLASH_TILE_AREA,
+                                  TPU_FLASH_TILES, flash_tile)
 
 
 def _blockwise_reference(q, k, v, *, causal, window, scale, q_offset, chunk):
@@ -96,40 +99,47 @@ def _blockwise_reference(q, k, v, *, causal, window, scale, q_offset, chunk):
     return out.astype(q.dtype)
 
 
-# nondiff args (all static/hashable: bools, ints, float-or-None, frozen
-# DesignPoint) come first in the bwd signature, per the argnums convention.
+# nondiff args (all static/hashable: bools, ints, float-or-None, the
+# (block_q, block_k) pair) come first in the bwd signature, per the argnums
+# convention.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _pallas_attention(q, k, v, causal, window, scale, q_offset, design,
+def _pallas_attention(q, k, v, causal, window, scale, q_offset, blocks,
                       interpret):
-    bq, bk = _mosaic_blocks(design)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   scale=scale, q_offset=q_offset,
-                                  block_q=bq, block_k=bk,
+                                  block_q=blocks[0], block_k=blocks[1],
                                   interpret=interpret)
 
 
-def _mosaic_blocks(design):
+def _mosaic_blocks(d, pinned: bool, sq: int, skv: int, head_dim: int):
+    """(block_q, block_k) for the Mosaic kernel. A pinned design or a
+    tuning-cache entry is taken as given (a 0 field means the kernel
+    default); a TPU call with neither takes, per side, the largest tile up
+    to the measured TPU tile (less for heads wider than 256) that divides
+    its length rounded up to 128."""
+    if d.backend == "tpu" and not (pinned or d.cache_hit):
+        cap = TPU_FLASH_TILE_AREA // max(256, -(-head_dim // 128) * 128)
+        bq, bk = (min(t, cap) for t in TPU_FLASH_TILES)
+        return flash_tile(sq, bq), flash_tile(skv, bk)
     dflt = DEFAULT_DESIGN["flash_attention"]
-    if design is None:
-        design = dflt
-    return design.block_q or dflt.block_q, design.block_k or dflt.block_k
+    return d.design.block_q or dflt.block_q, d.design.block_k or dflt.block_k
 
 
-def _pallas_fwd(q, k, v, causal, window, scale, q_offset, design, interpret):
-    bq, bk = _mosaic_blocks(design)
+def _pallas_fwd(q, k, v, causal, window, scale, q_offset, blocks, interpret):
     out, lse = flash_attention_pallas_fwd(
         q, k, v, causal=causal, window=window, scale=scale,
-        q_offset=q_offset, block_q=bq, block_k=bk, interpret=interpret)
+        q_offset=q_offset, block_q=blocks[0], block_k=blocks[1],
+        interpret=interpret)
     return out, (q, k, v, out, lse)
 
 
-def _pallas_bwd(causal, window, scale, q_offset, design, interpret, res, g):
+def _pallas_bwd(causal, window, scale, q_offset, blocks, interpret, res, g):
     # true flash backward (Pallas dQ + dK/dV kernels, LSE from forward)
     q, k, v, out, lse = res
-    bq, bk = _mosaic_blocks(design)
     return flash_attention_pallas_bwd(
         q, k, v, out, lse, g, causal=causal, window=window, scale=scale,
-        q_offset=q_offset, block_q=bq, block_k=bk, interpret=interpret)
+        q_offset=q_offset, block_q=blocks[0], block_k=blocks[1],
+        interpret=interpret)
 
 
 _pallas_attention.defvjp(_pallas_fwd, _pallas_bwd)
@@ -171,9 +181,18 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if d.impl == "naive":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   scale=scale, q_offset=q_offset)
+    if d.impl == "pallas" and d.variant == "triton":
+        return _triton_attention(q, k, v, causal, window, scale, q_offset,
+                                 d.design, d.interpret)
     if d.impl == "pallas":
-        fn = _triton_attention if d.variant == "triton" else _pallas_attention
-        return fn(q, k, v, causal, window, scale, q_offset, d.design,
-                  d.interpret)
+        blocks = _mosaic_blocks(d, design is not None, q.shape[1],
+                                k.shape[1], q.shape[-1])
+        tiles, live = tile_schedule(
+            q.shape[1], k.shape[1], causal=causal, window=window,
+            q_offset=q_offset, block_q=blocks[0], block_k=blocks[1])
+        obs.count("flash_attention.tiles", tiles)
+        obs.count("flash_attention.tiles_live", live)
+        return _pallas_attention(q, k, v, causal, window, scale, q_offset,
+                                 blocks, d.interpret)
     return _blockwise_reference(q, k, v, causal=causal, window=window,
                                 scale=scale, q_offset=q_offset, chunk=chunk)

@@ -84,6 +84,26 @@ DEFAULT_DESIGN = {
 }
 
 
+# The Mosaic flash kernel's (block_q, block_k) for a TPU call with no cache
+# entry and no pinned design: the largest tile a TPU v5e sweep found good at
+# head dim 128 (PERF.md), cut per call by ``flash_tile`` so that a short
+# sequence is not padded up to it. Tile rows x padded head dim stay within
+# TPU_FLASH_TILE_AREA, the most the v5e compiler placed in VMEM for the
+# backward at its default limit.
+TPU_FLASH_TILES = (1024, 1024)
+TPU_FLASH_TILE_AREA = 1024 * 256
+
+
+def flash_tile(seq_len: int, largest: int) -> int:
+    """The largest power-of-two tile from 128 up to ``largest`` that
+    divides ``seq_len`` rounded up to 128."""
+    padded = -(-seq_len // 128) * 128
+    tile = largest
+    while tile > 128 and padded % tile:
+        tile //= 2
+    return tile
+
+
 def as_design(design) -> DesignPoint:
     """Coerce a DesignPoint | 4-tuple | None-fields dict to a DesignPoint."""
     if isinstance(design, DesignPoint):

@@ -69,6 +69,31 @@ def test_flash_attention_fwd_bwd_internlm2_widths(one_chip):
         q, k, v, o, lse, do, interpret=False), q, kv, kv, q, lse, q)
 
 
+def test_flash_attention_at_the_cells_shape_and_resolved_tile(one_chip,
+                                                             monkeypatch):
+    """The training cell's attention call (B=2, S=4096, 16/8 heads x 128)
+    forward and backward through the public op, with the tile the TPU path
+    resolves for it, so a VMEM overrun at that tile fails here."""
+    from repro import obs
+    from repro.kernels import dispatch
+    from repro.kernels.flash_attention.ops import (_mosaic_blocks,
+                                                   flash_attention)
+
+    monkeypatch.setattr(dispatch, "current_backend", lambda: "tpu")
+    B, S, H, KVH, D = 2, 4096, 16, 8, 128
+    bq, bk = _mosaic_blocks(dispatch.resolve(
+        "auto", kernel="flash_attention", shape=(S, D)), False, S, S, D)
+    assert (bq, bk) != (128, 128)
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, S, KVH, D), jnp.bfloat16, one_chip)
+    tiles = obs.counter("flash_attention.tiles")
+    _compile(lambda q, k, v: flash_attention(q, k, v), q, kv, kv)
+    assert obs.counter("flash_attention.tiles") - tiles == \
+        (S // bq) * (S // bk)
+    _compile(lambda q, k, v, do: jax.vjp(flash_attention, q, k, v)[1](do),
+             q, kv, kv, q)
+
+
 def test_ssd_fwd_bwd_mamba2_widths(one_chip):
     B, S, H, P_, G, N, chunk = 1, 4096, 80, 64, 1, 128, 256
     x = _sds((B, S, H, P_), jnp.bfloat16, one_chip)
