@@ -74,6 +74,8 @@ class ModelConfig:
     attention: str = "gqa"         # "gqa" | "mla" | "none"
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    position_embedding: str = "rope"   # "rope" | "nope" (no positions)
+    attention_scale: float = 0.0       # softmax scale; 0 -> 1/sqrt(head_dim)
     sliding_window: int = 0        # 0 -> full attention
     # local:global layer pattern, e.g. (5, 1) = 5 sliding-window layers then 1 global.
     # (0, 0) -> uniform layers.
@@ -87,6 +89,9 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     # hybrid (zamba2): one SHARED attention block applied every k mamba layers
     shared_attn_every: int = 0
+    # hybrid (granite-4.0-h): the kinds of one period of layers, "mamba" or
+    # "attention"; every layer of either kind is followed by the MLP
+    layer_pattern: Tuple[str, ...] = ()
 
     # enc-dec (whisper)
     is_encoder_decoder: bool = False
@@ -100,6 +105,12 @@ class ModelConfig:
     norm: str = "rmsnorm"          # "rmsnorm" | "layernorm"
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    # muP-style multipliers (granite): the embedding is multiplied by
+    # embedding_multiplier, every residual branch by residual_multiplier,
+    # and the logits are divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     act: str = "silu"              # "silu" | "gelu"
     dtype: str = "bfloat16"        # activation/compute dtype for lowering
     param_dtype: str = "float32"
@@ -141,6 +152,11 @@ class ModelConfig:
             raise ValueError(f"unknown family {self.family!r}")
         if self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(
+                f"unknown position_embedding {self.position_embedding!r}")
+        if set(self.layer_pattern) - {"mamba", "attention"}:
+            raise ValueError(f"unknown layer kinds in {self.layer_pattern!r}")
         # validate impl strings HERE, not at resolve time deep inside a
         # jitted trace (function-local import: this module stays jax-free
         # at import time for config-only tooling)
@@ -153,6 +169,14 @@ class ModelConfig:
                 raise ValueError(
                     f"ModelConfig.{fld} must be () or a 4-tuple (block_q, "
                     f"block_k, num_warps, num_stages); got {pin!r}")
+
+    @property
+    def layer_types(self) -> list:
+        """The kind of each of the ``n_layers`` layers, as a list (the
+        ``layer_types`` of a Hugging Face hybrid config); [] where the
+        config has no layer pattern."""
+        pat = self.layer_pattern
+        return [pat[i % len(pat)] for i in range(self.n_layers)] if pat else []
 
     @property
     def d_head_q(self) -> int:
@@ -229,6 +253,10 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         total += cfg.n_layers * (attn_params() + moe_params())
     elif cfg.family == "ssm":
         total += cfg.n_layers * ssm_params()
+    elif cfg.family == "hybrid" and cfg.layer_pattern:
+        n_attn = cfg.layer_types.count("attention")
+        total += (cfg.n_layers - n_attn) * ssm_params() + n_attn * attn_params()
+        total += cfg.n_layers * mlp_params()       # an MLP in every layer
     elif cfg.family == "hybrid":
         total += cfg.n_layers * ssm_params()
         total += attn_params() + mlp_params()      # ONE shared attention block

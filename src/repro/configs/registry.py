@@ -19,11 +19,14 @@ _MODULES = {
     "whisper-base": "repro.configs.whisper_base",
     "cifar-cnn": "repro.configs.cifar_cnn",
     "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
+    "granite-4.0-h-micro": "repro.configs.granite_4_0_h_micro",
 }
 
 # The 10 assigned architectures (cifar-cnn is the paper-faithful extra;
-# deepseek-v2-lite is a beyond-assignment MLA+MoE composition bonus).
-_EXTRAS = ("cifar-cnn", "deepseek-v2-lite")
+# deepseek-v2-lite is a beyond-assignment MLA+MoE composition bonus;
+# granite-4.0-h-micro is the Mamba-2 + attention hybrid the chip benchmark
+# trains).
+_EXTRAS = ("cifar-cnn", "deepseek-v2-lite", "granite-4.0-h-micro")
 ASSIGNED_ARCHS: List[str] = [a for a in _MODULES if a not in _EXTRAS]
 BONUS_ARCHS: List[str] = ["deepseek-v2-lite"]
 

@@ -81,8 +81,8 @@ def gqa_forward(params, x, cfg: ModelConfig, *, positions=None, window: int = 0,
         q, k = _rope(cfg, q, k, positions)
     out = flash_attention(
         q, k, v, causal=causal and cross_x is None, window=window,
-        chunk=cfg.attention_chunk, impl=cfg.attention_impl,
-        design=cfg.attention_design or None)
+        scale=cfg.attention_scale or None, chunk=cfg.attention_chunk,
+        impl=cfg.attention_impl, design=cfg.attention_design or None)
     B, S = x.shape[:2]
     out = mdot(out.reshape(B, S, -1), params["wo"], dtype)
     if not return_cache:
@@ -178,7 +178,7 @@ def gqa_decode(params, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
             q = q + params["bq"].astype(dtype)
         q = q.reshape(B, 1, H, Dh)
         k, v = _cache_kv(cfg, cache, dtype)
-        out = _cache_attend(q, k, v, kpos=None)
+        out = _cache_attend(q, k, v, kpos=None, scale=cfg.attention_scale)
         return mdot(out.reshape(B, 1, -1), params["wo"], dtype), cache
 
     q, k_new, v_new = _qkv(params, x, x, cfg, dtype)
@@ -214,18 +214,19 @@ def gqa_decode(params, x, cache, pos, cfg: ModelConfig, *, window: int = 0,
     k, v = _cache_kv(cfg, new_cache, dtype)
 
     kpos = _slot_positions(pos, L, window)
-    out = _cache_attend(q, k, v, kpos=kpos)
+    out = _cache_attend(q, k, v, kpos=kpos, scale=cfg.attention_scale)
     out = mdot(out.reshape(B, 1, -1), params["wo"], dtype)
     return out, new_cache
 
 
-def _cache_attend(q, k, v, kpos):
+def _cache_attend(q, k, v, kpos, scale: float = 0.0):
     """Single-query attention over a cache. q: (B,1,H,Dh); k/v: (B,L,KVH,Dh);
-    kpos: (L,) or per-request (B,L) absolute positions, or None (cross)."""
+    kpos: (L,) or per-request (B,L) absolute positions, or None (cross).
+    ``scale``: the softmax scale; 0 -> 1/sqrt(Dh)."""
     B, _, H, Dh = q.shape
     KVH = k.shape[2]
     G = H // KVH
-    scale = Dh ** -0.5
+    scale = scale or Dh ** -0.5
     qf = (q.astype(jnp.float32) * scale).reshape(B, KVH, G, Dh)
     s = jnp.einsum("bhgd,blhd->bhgl", qf, k.astype(jnp.float32))
     if kpos is not None:
@@ -321,7 +322,7 @@ def gqa_decode_paged(params, x, cache, pos, block_tables, cfg: ModelConfig,
     k, v = _cache_kv(cfg, {kk: gather(vv) for kk, vv in new_cache.items()},
                      dtype)
     kpos = _slot_positions(pos, M * P, 0)
-    out = _cache_attend(q, k, v, kpos=kpos)
+    out = _cache_attend(q, k, v, kpos=kpos, scale=cfg.attention_scale)
     out = mdot(out.reshape(B, 1, -1), params["wo"], dtype)
     return out, new_cache
 

@@ -6,7 +6,9 @@ single ``lax.scan`` over stacked parameters:
   dense/moe/vlm : unit = 1 layer  (gemma3: unit = 5 local + 1 global)
   ssm           : unit = 1 mamba layer
   hybrid        : unit = shared_attn_every mamba layers, with the ONE shared
-                  attention block (zamba2) applied before each unit
+                  attention block (zamba2) applied before each unit; or
+                  unit = one period of ``layer_pattern`` (granite-4.0-h:
+                  mamba and attention layers, each followed by an MLP)
   audio         : separate encoder / decoder stacks (whisper)
 
 Remaining layers (n_layers % unit_len) form an unrolled tail. Within a unit
@@ -15,18 +17,28 @@ Python, so a unit body is trace-time specialized; across units everything is
 structurally identical, which keeps compiled HLO size O(unit) instead of
 O(n_layers) — essential for the 94-layer MoE dry-run at 512 devices.
 
+A unit whose layers share one structure stacks their weights as
+``blocks/<leaf>`` with axes (n_units, unit_len, ...) and is checkpointed
+whole. A mixed unit (mamba and attention layers) stacks each kind apart,
+``blocks/<block>/<leaf>`` with axes (n_units, layers of that kind, ...), and
+is checkpointed layer by layer, so its backward holds one layer's
+recomputed activations at a time.
+
 Caches are dicts keyed by position-in-unit (string), stacked across units on
 the leading axis, so sliding-window layers can hold (window)-sized caches
 next to full-length global caches in the same scan.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import logical_constraint
 from repro.models import attention as attn, mamba2, moe
@@ -42,6 +54,7 @@ class LayerKind:
     window: int = 0     # sliding window for attn (0 = full)
     use_moe: bool = False
     cross: bool = False  # adds cross-attention (whisper decoder)
+    mlp: bool = False    # mamba only: the MLP sublayer follows the mixer
 
 
 def _tree_index(tree, i):
@@ -59,7 +72,9 @@ class Model:
         self.cfg = cfg
         self.dtype = jnp.dtype(cfg.dtype)
         self.unit_kinds, self.n_units, self.tail_kinds = self._plan(cfg)
-        self.use_rope = cfg.family != "audio"
+        self.unit_slots = self._slots(self.unit_kinds)
+        self.use_rope = (cfg.family != "audio"
+                         and cfg.position_embedding == "rope")
 
     # ------------------------------------------------------------------
     # layer plan
@@ -69,6 +84,9 @@ class Model:
     def _plan(cfg: ModelConfig) -> Tuple[List[LayerKind], int, List[LayerKind]]:
         if cfg.family == "ssm":
             unit = [LayerKind("mamba")]
+        elif cfg.family == "hybrid" and cfg.layer_pattern:
+            unit = [LayerKind("mamba", mlp=True) if t == "mamba"
+                    else LayerKind("attn") for t in cfg.layer_pattern]
         elif cfg.family == "hybrid":
             unit = [LayerKind("mamba")] * cfg.shared_attn_every
         elif cfg.family == "audio":
@@ -84,7 +102,43 @@ class Model:
             unit = [dataclasses.replace(k, use_moe=True) for k in unit]
         n_units, rem = divmod(cfg.n_layers, len(unit))
         tail = unit[:rem]
+        if len({k.block for k in tail}) > 1:
+            raise ValueError(f"{cfg.name}: {cfg.n_layers} layers leave a "
+                             f"mixed part of a {len(unit)}-layer period")
         return unit, n_units, tail
+
+    @staticmethod
+    def _slots(kinds: List[LayerKind]) -> List[Tuple[Optional[str], int]]:
+        """Where each position of a unit keeps its weights in ``blocks``:
+        (None, i) where every position has the same block, else
+        (block, index among the positions of that block)."""
+        if len({k.block for k in kinds}) == 1:
+            return [(None, i) for i in range(len(kinds))]
+        seen: collections.Counter = collections.Counter()
+        slots = []
+        for k in kinds:
+            slots.append((k.block, seen[k.block]))
+            seen[k.block] += 1
+        return slots
+
+    @property
+    def mixed_unit(self) -> bool:
+        return self.unit_slots[0][0] is not None
+
+    def _layer_params(self, unit_p, i: int):
+        """Position ``i``'s weights within one unit's slice of ``blocks``."""
+        group, j = self.unit_slots[i]
+        return _tree_index(unit_p if group is None else unit_p[group], j)
+
+    def layer_counts(self) -> Dict[str, int]:
+        """Layers of each kind the built program runs (zamba2's shared
+        block counts once per application)."""
+        kinds = list(self.unit_kinds) * self.n_units + list(self.tail_kinds)
+        out = {"mamba": sum(k.block == "mamba" for k in kinds),
+               "attention": sum(k.block == "attn" for k in kinds)}
+        if self.cfg.shared_attn_every:
+            out["attention"] += self.n_units
+        return out
 
     # ------------------------------------------------------------------
     # paged-cache capability
@@ -103,7 +157,7 @@ class Model:
         """True if any layer can use a paged KV pool (the serving engine's
         ``kv_layout="auto"`` resolves to paged exactly then)."""
         kinds = list(self.unit_kinds) + list(self.tail_kinds)
-        if self.cfg.family == "hybrid":
+        if self.cfg.shared_attn_every:
             kinds.append(LayerKind("attn"))
         return any(self.pageable(k) for k in kinds)
 
@@ -115,8 +169,12 @@ class Model:
         cfg = self.cfg
         ks = jax.random.split(key, 6)
         if kind.block == "mamba":
-            return {"ln1": init_norm(ks[0], cfg.d_model, cfg.norm),
-                    "mamba": mamba2.init_mamba(ks[1], cfg)}
+            p = {"ln1": init_norm(ks[0], cfg.d_model, cfg.norm),
+                 "mamba": mamba2.init_mamba(ks[1], cfg)}
+            if kind.mlp:
+                p["ln2"] = init_norm(ks[2], cfg.d_model, cfg.norm)
+                p["mlp"] = init_mlp(ks[5], cfg.d_model, cfg.d_ff)
+            return p
         p = {"ln1": init_norm(ks[0], cfg.d_model, cfg.norm),
              "ln2": init_norm(ks[1], cfg.d_model, cfg.norm)}
         if cfg.attention == "mla":
@@ -154,15 +212,25 @@ class Model:
             for i, kind in enumerate(self.unit_kinds):
                 per_pos.append(jax.vmap(
                     lambda k, kind=kind: self._init_block(k, kind))(bkeys[:, i]))
-            # per_pos[i] leaves: (n_units, ...); stack positions on axis 1
-            params["blocks"] = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs, axis=1), *per_pos)
+            # per_pos[i] leaves: (n_units, ...); stack positions on axis 1,
+            # apart per block where the unit is mixed
+            def stack(trees):
+                return jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs, axis=1), *trees)
+            if self.mixed_unit:
+                params["blocks"] = {
+                    g: stack([t for t, (b, _) in zip(per_pos,
+                                                     self.unit_slots)
+                              if b == g])
+                    for g in dict.fromkeys(b for b, _ in self.unit_slots)}
+            else:
+                params["blocks"] = stack(per_pos)
         if self.tail_kinds:
             tkeys = jax.random.split(keys[4], len(self.tail_kinds) * 2)
             params["tail"] = _tree_stack([
                 self._init_block(jax.random.fold_in(keys[4], i), kind)
                 for i, kind in enumerate(self.tail_kinds)])
-        if cfg.family == "hybrid":
+        if cfg.shared_attn_every:
             params["shared"] = self._init_block(keys[5], LayerKind("attn"))
         if cfg.is_encoder_decoder:
             ekeys = jax.random.split(keys[6], cfg.n_encoder_layers)
@@ -198,7 +266,13 @@ class Model:
                     cache["m"] = mc
                 else:
                     y = mamba2.mamba_forward(p["mamba"], x, cfg)
-                return h + y, cache, aux
+                h = self._residual(h, y)
+            if not kind.mlp:
+                return h, cache, aux
+            with jax.named_scope("mlp"):
+                x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
+                y = apply_mlp(p["mlp"], x, cfg.act, self.dtype)
+                return self._residual(h, y), cache, aux
 
         with jax.named_scope("attention"):
             x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
@@ -222,7 +296,7 @@ class Model:
                 else:
                     y = attn.gqa_forward(p["attn"], x, cfg, positions=pos,
                                          window=kind.window, causal=causal)
-            h = h + y
+            h = self._residual(h, y)
             if kind.cross and enc_out is not None:
                 x = apply_norm(p["lnx"], h, cfg.norm, cfg.norm_eps)
                 if mode == "prefill":
@@ -232,14 +306,20 @@ class Model:
                     cache["x"] = xc
                 else:
                     y = attn.gqa_forward(p["xattn"], x, cfg, cross_x=enc_out)
-                h = h + y
+                h = self._residual(h, y)
         with jax.named_scope("moe" if kind.use_moe else "mlp"):
             x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
             if kind.use_moe:
                 y, aux = moe.moe_forward(p["moe"], x, cfg)
             else:
                 y = apply_mlp(p["mlp"], x, cfg.act, self.dtype)
-            return h + y, cache, aux
+            return self._residual(h, y), cache, aux
+
+    def _residual(self, h, y):
+        """The residual add, with the branch scaled by
+        ``residual_multiplier`` where it is not 1."""
+        r = self.cfg.residual_multiplier
+        return h + y if r == 1.0 else h + y * r
 
     # ------------------------------------------------------------------
     # block execution (decode: one token)
@@ -251,7 +331,12 @@ class Model:
         if kind.block == "mamba":
             x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
             y, mc = mamba2.mamba_decode(p["mamba"], x, cache["m"], cfg)
-            return h + y, {"m": mc}
+            h = self._residual(h, y)
+            if kind.mlp:
+                x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
+                h = self._residual(
+                    h, apply_mlp(p["mlp"], x, cfg.act, self.dtype))
+            return h, {"m": mc}
         new_cache = {}
         x = apply_norm(p["ln1"], h, cfg.norm, cfg.norm_eps)
         if "p" in cache:        # paged KV pool (layout owned by repro.dist)
@@ -271,19 +356,19 @@ class Model:
                 use_rope=self.use_rope)
         if ac is not None:
             new_cache["a"] = ac
-        h = h + y
+        h = self._residual(h, y)
         if kind.cross and "x" in cache:
             x = apply_norm(p["lnx"], h, cfg.norm, cfg.norm_eps)
             y, _ = attn.gqa_decode(p["xattn"], x, cache["x"], pos, cfg,
                                    cross=True)
             new_cache["x"] = cache["x"]
-            h = h + y
+            h = self._residual(h, y)
         x = apply_norm(p["ln2"], h, cfg.norm, cfg.norm_eps)
         if kind.use_moe:
             y, _ = moe.moe_forward(p["moe"], x, cfg)
         else:
             y = apply_mlp(p["mlp"], x, cfg.act, self.dtype)
-        return h + y, new_cache
+        return self._residual(h, y), new_cache
 
     def _remat(self, fn):
         if self.cfg.remat_policy == "dots":
@@ -315,6 +400,8 @@ class Model:
         if cfg.family == "audio":
             pos = jnp.arange(tokens.shape[1]) if positions is None else positions
             h = h + sinusoidal_embedding(pos, cfg.d_model).astype(self.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * cfg.embedding_multiplier
         return h
 
     def _default_positions(self, B, S, offset=0):
@@ -351,22 +438,30 @@ class Model:
         if positions is None:
             positions = self._default_positions(B, S)
         enc_out = self._encode(params, frames) if cfg.is_encoder_decoder else None
+        for kind, n in self.layer_counts().items():
+            obs.count(f"model.layers.{kind}", n)
         with jax.named_scope("embed"):
             h = self._embed(params, tokens, positions, vision_embeds)
+        remat_layers = cfg.remat and self.mixed_unit
 
         def unit_body(carry, unit_p):
             h, aux = carry
-            if cfg.family == "hybrid":
+            if cfg.shared_attn_every:
                 h, _, _ = self._block_full(params["shared"], h,
                                            LayerKind("attn"), positions,
                                            "train", enc_out)
             for i, kind in enumerate(self.unit_kinds):
-                h, _, a = self._block_full(_tree_index(unit_p, i), h, kind,
-                                           positions, "train", enc_out)
+                block = functools.partial(self._block_full, kind=kind,
+                                          positions=positions, mode="train",
+                                          enc_out=enc_out)
+                if remat_layers:
+                    block = self._remat(block)
+                h, _, a = block(self._layer_params(unit_p, i), h)
                 aux = aux + a
             return (h, aux), None
 
-        body = self._remat(unit_body) if cfg.remat else unit_body
+        body = (self._remat(unit_body) if cfg.remat and not remat_layers
+                else unit_body)
         aux = jnp.zeros((), jnp.float32)
         with jax.named_scope("layers"):
             if "blocks" in params:
@@ -389,8 +484,12 @@ class Model:
 
     def _head(self, params, h):
         if self.cfg.tie_embeddings:
-            return mdot(h, params["embed"]["table"].T, self.dtype)
-        return mdot(h, params["head"]["w"], self.dtype)
+            logits = mdot(h, params["embed"]["table"].T, self.dtype)
+        else:
+            logits = mdot(h, params["head"]["w"], self.dtype)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        return logits
 
     # -------------------------- prefill ------------------------------
 
@@ -438,15 +537,15 @@ class Model:
 
         def unit_body(h, unit_p):
             caches = {}
-            if cfg.family == "hybrid":
+            if cfg.shared_attn_every:
                 h, sc, _ = self._block_full(params["shared"], h,
                                             LayerKind("attn"), positions,
                                             "prefill", enc_out, length=length)
                 caches["shared"] = pad_cache(sc, LayerKind("attn"))
             for i, kind in enumerate(self.unit_kinds):
-                h, c, _ = self._block_full(_tree_index(unit_p, i), h, kind,
-                                           positions, "prefill", enc_out,
-                                           length=length)
+                h, c, _ = self._block_full(self._layer_params(unit_p, i), h,
+                                           kind, positions, "prefill",
+                                           enc_out, length=length)
                 caches[str(i)] = pad_cache(c, kind)
             return h, caches
 
@@ -496,16 +595,16 @@ class Model:
         def unit_body(h, xs):
             unit_p, unit_c = xs
             new_c = {}
-            if cfg.family == "hybrid":
+            if cfg.shared_attn_every:
                 h, sc = self._block_decode(params["shared"], h,
                                            LayerKind("attn"),
                                            unit_c["shared"], pos, positions,
                                            block_tables)
                 new_c["shared"] = sc
             for i, kind in enumerate(self.unit_kinds):
-                h, c = self._block_decode(_tree_index(unit_p, i), h, kind,
-                                          unit_c[str(i)], pos, positions,
-                                          block_tables)
+                h, c = self._block_decode(self._layer_params(unit_p, i), h,
+                                          kind, unit_c[str(i)], pos,
+                                          positions, block_tables)
                 new_c[str(i)] = c
             return h, new_c
 
@@ -564,7 +663,7 @@ class Model:
         if self.n_units:
             unit_c = {str(i): block_cache(k)
                       for i, k in enumerate(self.unit_kinds)}
-            if cfg.family == "hybrid":
+            if cfg.shared_attn_every:
                 unit_c["shared"] = block_cache(LayerKind("attn"))
             cache["units"] = jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(a[None], (self.n_units,) + a.shape),

@@ -50,7 +50,7 @@ def lm_chunk_hlo(arch: str, sharding=None) -> str:
 
     cfg = dataclasses.replace(registry.get_smoke_config(arch),
                               dtype="bfloat16")
-    if cfg.family == "ssm":
+    if cfg.ssm is not None:
         cfg = dataclasses.replace(
             cfg, ssm=dataclasses.replace(cfg.ssm, chunk_size=128))
     adapter = LMAdapter(cfg, OptimizerConfig(
@@ -97,3 +97,18 @@ def test_cpu_train_step_matmuls_are_scoped(arch, block):
     hlo = lm_chunk_hlo(arch)
     check_scopes(hlo, ("dot",), unnamed_ok=True)
     assert f"/{block}/" in hlo
+
+
+def test_hybrid_step_counts_its_layers_and_scopes_each_kind():
+    """granite-4.0-h-micro's smoke twin of the benchmark's 10-layer cut:
+    tracing its step counts 9 Mamba-2 layers and 1 attention layer
+    (``model.layers.*``), and its matmuls fall under ``mixer``,
+    ``attention`` and ``mlp``."""
+    from repro import obs
+    obs.reset()
+    hlo = lm_chunk_hlo("granite-4.0-h-micro")
+    assert obs.counter("model.layers.mamba") == 9
+    assert obs.counter("model.layers.attention") == 1
+    check_scopes(hlo, ("dot",), unnamed_ok=True)
+    for block in ("mixer", "attention", "mlp"):
+        assert f"/{block}/" in hlo

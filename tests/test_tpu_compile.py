@@ -94,6 +94,32 @@ def test_flash_attention_at_the_cells_shape_and_resolved_tile(one_chip,
              q, kv, kv, q)
 
 
+def test_flash_attention_at_granite_widths(one_chip, monkeypatch):
+    """granite-4.0-h-micro's attention layer in its training cell: B=2,
+    S=4096, 32/8 heads x 64 (a head half the lane width), the softmax
+    scale 1/64 and no rotary positions, forward and backward through the
+    public op at the TPU tile; the tile counters record the call."""
+    from repro import obs
+    from repro.kernels import dispatch
+    from repro.kernels.flash_attention.ops import flash_attention
+
+    monkeypatch.setattr(dispatch, "current_backend", lambda: "tpu")
+    B, S, H, KVH, D = 2, 4096, 32, 8, 64
+    q = _sds((B, S, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, S, KVH, D), jnp.bfloat16, one_chip)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, scale=1 / 64)
+
+    tiles = obs.counter("flash_attention.tiles")
+    live = obs.counter("flash_attention.tiles_live")
+    _compile(attend, q, kv, kv)
+    assert obs.counter("flash_attention.tiles") - tiles == 16
+    assert obs.counter("flash_attention.tiles_live") - live == 10
+    _compile(lambda q, k, v, do: jax.vjp(attend, q, k, v)[1](do),
+             q, kv, kv, q)
+
+
 def test_ssd_fwd_bwd_mamba2_widths(one_chip):
     B, S, H, P_, G, N, chunk = 1, 4096, 80, 64, 1, 128, 256
     x = _sds((B, S, H, P_), jnp.bfloat16, one_chip)
@@ -263,6 +289,10 @@ def test_kernel_instruction_names_are_declared(one_chip, which, names,
                         "flash_attention_pallas_bwd_dq",
                         "flash_attention_pallas_bwd_dkv"}),
     ("mamba2-2.7b", {"ssd_chunk_pallas", "ssd_chunk_pallas_bwd"}),
+    ("granite-4.0-h-micro", {"flash_attention_pallas_fwd",
+                             "flash_attention_pallas_bwd_dq",
+                             "flash_attention_pallas_bwd_dkv",
+                             "ssd_chunk_pallas", "ssd_chunk_pallas_bwd"}),
 ])
 def test_train_step_scopes_and_kernel_names(one_chip, monkeypatch, arch,
                                             names):
@@ -277,6 +307,6 @@ def test_train_step_scopes_and_kernel_names(one_chip, monkeypatch, arch,
     check_scopes(hlo, ("convolution", "dot"))
     calls = _kernel_calls(hlo)
     assert {_declared(n) for n in calls} == names
-    block = "attention" if arch.startswith("internlm2") else "mixer"
     for name, op_name in calls.items():
+        block = "attention" if name.startswith("flash") else "mixer"
         assert {"layers", block} <= scope_parts(op_name), (name, op_name)
