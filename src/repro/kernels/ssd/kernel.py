@@ -2,9 +2,17 @@
 
 TPU adaptation (vs the Triton SSD kernels in the Mamba-2 release):
   * The O(L^2) intra-chunk block — (C·Bᵀ ∘ decay-mask) @ (dt·x) — is the
-    MXU hot spot; it runs as one Pallas program per (batch·head, chunk) with
-    chunk length L and head dim P as VMEM-resident tiles (L, P aligned to
-    128 by the caller for real-TPU runs).
+    MXU hot spot. The grid is (sequence·group, chunk, head of the group),
+    heads innermost: B and C are read in the model's own (B, S, G, N)
+    layout, once per (sequence, group, chunk), since the pipeline fetches
+    nothing for a block index that does not change, and C·Bᵀ is computed
+    at the group's first head into VMEM and reused by the rest. What
+    depends on a head's dt and A (the decay matrix, y, the chunk state and
+    cum) stays per head. The backward sums ``dGseg`` and the state terms
+    of dB over the group's heads in VMEM and multiplies by B and C once,
+    at the group's last head. Chunk length L and head dim P are
+    VMEM-resident tiles (L, P aligned to 128 by the caller for real-TPU
+    runs; N too when G > 1).
   * The inter-chunk state recurrence is sequential and tiny
     (nc elements of (P,N) state); it stays in JAX as lax.associative_scan —
     on TPU this is a log-depth tree of elementwise ops, not worth a kernel.
@@ -22,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import dispatch
 
@@ -59,12 +68,22 @@ class _Chunk:
         return jnp.sum(jnp.where(self.last, col, 0.0), axis=0, keepdims=True)
 
 
+def _cbt(c_ref, b_ref):
+    """The group's C·Bᵀ, (L, L) in f32."""
+    return jax.lax.dot_general(c_ref[0].astype(jnp.float32),
+                               b_ref[0].astype(jnp.float32),
+                               (((1,), (1,)), ((), ())))
+
+
 def _ssd_chunk_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
-                      y_ref, state_ref, cum_ref):
+                      y_ref, state_ref, cum_ref, scores_ref):
+    @pl.when(pl.program_id(2) == 0)             # the group's first head
+    def _():
+        scores_ref[...] = _cbt(c_ref, b_ref)
+
     x = x_ref[0].astype(jnp.float32)            # (L, P)
     dt_r = dt_ref[0].astype(jnp.float32)        # (1, L)
     bm = b_ref[0].astype(jnp.float32)           # (L, N)
-    cm = c_ref[0].astype(jnp.float32)           # (L, N)
     a = a_ref[0, 0, 0]                          # scalar A (negative)
 
     ch = _Chunk(x.shape[0])
@@ -75,9 +94,8 @@ def _ssd_chunk_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
     # segsum decay matrix: seg[i, j] = exp(cum_i - cum_j) for i >= j else 0
     seg = jnp.exp(jnp.where(ch.tri, cum - cum_r, -jnp.inf))
 
-    scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))  # (L, L)
     dx = dt * x                                                     # (L, P)
-    y = jax.lax.dot(scores * seg, dx)                               # (L, P)
+    y = jax.lax.dot(scores_ref[...] * seg, dx)                      # (L, P)
 
     # chunk-local final state: sum_j exp(cum_end - cum_j) dt_j x_j ⊗ B_j
     w = jnp.exp(ch.at_last(cum) - cum) * dt                         # (L, 1)
@@ -91,15 +109,26 @@ def _ssd_chunk_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
 
 def _ssd_chunk_bwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
                           dy_ref, dstate_ref, dcum_ref,
-                          dx_ref, ddt_ref, db_ref, dc_ref, da_ref):
+                          dx_ref, ddt_ref, db_ref, dc_ref, da_ref,
+                          scores_ref, dgseg_ref):
     """Intra-chunk SSD backward. Given cotangents of (y_intra, chunk-local
-    state, cum), produce (dx, ddt, dB, dC, da) for one (batch·head, chunk)
-    tile. All L×L work is MXU matmuls; cum is recomputed in VMEM (cheaper
-    than streaming it back from HBM)."""
+    state, cum), produce (dx, ddt, da) for one (head, chunk) tile and add
+    the head's share to its group's (dB, dC). All L×L work is MXU matmuls;
+    cum is recomputed in VMEM (cheaper than streaming it back from HBM).
+    The group's dB / dC blocks stay resident across its heads: dB gathers
+    the state terms, ``dgseg_ref`` the heads' ``dG ∘ seg``, and the last
+    head multiplies that sum by B and C once."""
+    head = pl.program_id(2)
+
+    @pl.when(head == 0)
+    def _():
+        scores_ref[...] = _cbt(c_ref, b_ref)
+        dgseg_ref[...] = jnp.zeros(dgseg_ref.shape, jnp.float32)
+        db_ref[...] = jnp.zeros(db_ref.shape, jnp.float32)
+
     x = x_ref[0].astype(jnp.float32)            # (L, P)
     dt_r = dt_ref[0].astype(jnp.float32)        # (1, L)
     bm = b_ref[0].astype(jnp.float32)           # (L, N)
-    cm = c_ref[0].astype(jnp.float32)           # (L, N)
     a = a_ref[0, 0, 0]
     dy = dy_ref[0].astype(jnp.float32)          # (L, P)
     dS = dstate_ref[0, 0].astype(jnp.float32)   # (P, N)
@@ -109,16 +138,13 @@ def _ssd_chunk_bwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
     dt = ch.col(dt_r)                           # (L, 1)
     cum = ch.cumsum(dt_r * a)                   # (L, 1)
     seg = jnp.exp(jnp.where(ch.tri, cum - ch.row(cum), -jnp.inf))
-    scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))  # C·Bᵀ
-    G = scores * seg
+    G = scores_ref[...] * seg
     dx_in = dt * x                                                   # (L,P)
 
     # --- y_intra = G @ dx_in ---
     dG = jax.lax.dot_general(dy, dx_in, (((1,), (1,)), ((), ())))    # (L,L)
     d_dx = jax.lax.dot_general(G, dy, (((0,), (0,)), ((), ())))      # (L,P)
-    dGseg = dG * seg                                                 # masked
-    dc = jax.lax.dot(dGseg, bm)                                      # (L,N)
-    db = jax.lax.dot_general(dGseg, cm, (((0,), (0,)), ((), ())))    # (L,N)
+    dgseg_ref[...] += dG * seg                                       # masked
     E = dG * G                                                       # (L,L)
     dcum = (ch.col(dcum_r) + jnp.sum(E, axis=1, keepdims=True)
             - ch.col(jnp.sum(E, axis=0, keepdims=True)))             # (L,1)
@@ -130,7 +156,7 @@ def _ssd_chunk_bwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
     dS_b = jax.lax.dot_general(bm, dS, (((1,), (1,)), ((), ())))     # (L,P)
     dw = jnp.sum(x * dS_b, axis=1, keepdims=True)                    # (L,1)
     dx = w * dS_b
-    db = db + w * jax.lax.dot(x, dS)                                 # (L,N)
+    db_ref[0, ...] += w * jax.lax.dot(x, dS)                         # (L,N)
     dcum = dcum - dw * w + jnp.where(
         ch.last, jnp.sum(dw * w, axis=0, keepdims=True), 0.0)
     ddt = dw * wexp
@@ -145,9 +171,57 @@ def _ssd_chunk_bwd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref,
 
     dx_ref[0, ...] = dx
     ddt_ref[0, ...] = ddt_r
-    db_ref[0, ...] = db
-    dc_ref[0, ...] = dc
     da_ref[0, 0] = jnp.sum(dt_r * rev, axis=1, keepdims=True)
+
+    @pl.when(head == pl.num_programs(2) - 1)    # the group's last head
+    def _():
+        dgs = dgseg_ref[...]                    # Σ_heads dG ∘ seg
+        dc_ref[0, ...] = jax.lax.dot(dgs, bm)                        # (L,N)
+        db_ref[0, ...] += jax.lax.dot_general(
+            dgs, c_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())))
+
+
+class _Grid:
+    """Grid (sequence·group, chunk, head of the group) and its index maps.
+    Per-head arrays are flattened to (B·H, S, ·), where the group's heads
+    are consecutive (head h reads group h // rep), so grid step
+    (bg, ci, r) is head ``bg·rep + r``; B and C, and dB and dC, are
+    (B, S, G·N), read per (sequence, group, chunk)."""
+
+    def __init__(self, Bsz, H, G, nc, chunk):
+        self.rep, self.G, self.chunk = H // G, G, chunk
+        self.grid = (Bsz * G, nc, self.rep)
+
+    def head(self, tail):
+        """Block (1, chunk, tail) of a per-head (B·H, S, tail) array."""
+        return pl.BlockSpec((1, self.chunk, tail),
+                            lambda bg, ci, r: (bg * self.rep + r, ci, 0))
+
+    def head_row(self):
+        """Block (1, 1, chunk) of a per-head (B·H, 1, S) array."""
+        return pl.BlockSpec((1, 1, self.chunk),
+                            lambda bg, ci, r: (bg * self.rep + r, 0, ci))
+
+    def head_state(self, shape):
+        """Block (1, 1) + shape of a per-head (B·H, nc) + shape array."""
+        return pl.BlockSpec(
+            (1, 1) + shape,
+            lambda bg, ci, r: (bg * self.rep + r, ci) + (0,) * len(shape))
+
+    def head_a(self):
+        return pl.BlockSpec((1, 1, 1),
+                            lambda bg, ci, r: (bg * self.rep + r, 0, 0))
+
+    def group(self, N):
+        """Block (1, chunk, N) of a (B, S, G·N) array: the group's."""
+        return pl.BlockSpec((1, self.chunk, N),
+                            lambda bg, ci, r: (bg // self.G, ci,
+                                               bg % self.G))
+
+    def params(self):
+        # the heads carry the group's scratch: they run in order
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -161,51 +235,39 @@ def ssd_chunk_pallas_bwd(x, dt, A, Bm, Cm, dy, dstates, dcum, *,
         interpret = dispatch.interpret_default()
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
     nc = S // chunk
     BH = Bsz * H
+    g = _Grid(Bsz, H, G, nc, chunk)
 
     xf = jnp.swapaxes(x, 1, 2).reshape(BH, S, P)
     dtf = jnp.swapaxes(dt, 1, 2).reshape(BH, 1, S)
-    bf = jnp.swapaxes(jnp.repeat(Bm, rep, axis=2), 1, 2).reshape(BH, S, N)
-    cf = jnp.swapaxes(jnp.repeat(Cm, rep, axis=2), 1, 2).reshape(BH, S, N)
     af = jnp.tile(A.astype(jnp.float32)[None, :], (Bsz, 1)).reshape(BH, 1, 1)
     dyf = jnp.swapaxes(dy.astype(jnp.float32), 1, 2).reshape(BH, S, P)
     dsf = jnp.swapaxes(dstates.astype(jnp.float32), 1, 2).reshape(
         BH, nc, P, N)
     dcf = jnp.swapaxes(dcum.astype(jnp.float32), 1, 2).reshape(BH, 1, S)
 
-    grid = (BH, nc)
     dx, ddt, db, dc, da = pl.pallas_call(
         _ssd_chunk_bwd_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((BH, S, P), jnp.float32),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, N), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, N), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, S, G * N), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, S, G * N), jnp.float32),
             jax.ShapeDtypeStruct((BH, nc, 1, 1), jnp.float32),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bh, ci: (bh, 0, 0)),
-            pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda bh, ci: (bh, ci, 0, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda bh, ci: (bh, ci, 0, 0)),
-        ),
+        grid=g.grid,
+        in_specs=[g.head(P), g.head_row(), g.group(N), g.group(N), g.head_a(),
+                  g.head(P), g.head_state((P, N)), g.head_row()],
+        out_specs=(g.head(P), g.head_row(), g.group(N), g.group(N),
+                   g.head_state((1, 1))),
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), jnp.float32),   # C·Bᵀ
+                        pltpu.VMEM((chunk, chunk), jnp.float32)],  # Σ dGseg
+        compiler_params=g.params(),
         interpret=interpret,
         name="ssd_chunk_pallas_bwd",
-    )(xf, dtf, bf, cf, af, dyf, dsf, dcf)
+    )(xf, dtf, Bm.reshape(Bsz, S, G * N), Cm.reshape(Bsz, S, G * N), af,
+      dyf, dsf, dcf)
 
     def unflat(t, extra):
         return jnp.swapaxes(t.reshape((Bsz, H) + extra), 1, 2)
@@ -213,10 +275,8 @@ def ssd_chunk_pallas_bwd(x, dt, A, Bm, Cm, dy, dstates, dcum, *,
     dx_out = unflat(dx, (S, P))
     ddt_out = unflat(ddt, (S,))
     dA_out = jnp.sum(da.reshape(Bsz, H, nc), axis=(0, 2))
-    # grouped B/C: sum gradients over the rep heads sharing each group
-    db_out = unflat(db, (S, N)).reshape(Bsz, S, G, rep, N).sum(3)
-    dc_out = unflat(dc, (S, N)).reshape(Bsz, S, G, rep, N).sum(3)
-    return dx_out, ddt_out, dA_out, db_out, dc_out
+    return (dx_out, ddt_out, dA_out, db.reshape(Bsz, S, G, N),
+            dc.reshape(Bsz, S, G, N))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -230,19 +290,16 @@ def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, chunk: int = 128,
         interpret = dispatch.interpret_default()
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
-    rep = H // G
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
     BH = Bsz * H
+    g = _Grid(Bsz, H, G, nc, chunk)
 
     # flatten to (B*H, S, ·) batch-head major
     xf = jnp.swapaxes(x, 1, 2).reshape(BH, S, P)
     dtf = jnp.swapaxes(dt, 1, 2).reshape(BH, 1, S)
-    bf = jnp.swapaxes(jnp.repeat(Bm, rep, axis=2), 1, 2).reshape(BH, S, N)
-    cf = jnp.swapaxes(jnp.repeat(Cm, rep, axis=2), 1, 2).reshape(BH, S, N)
     af = jnp.tile(A.astype(jnp.float32)[None, :], (Bsz, 1)).reshape(BH, 1, 1)
 
-    grid = (BH, nc)
     y, states, cum = pl.pallas_call(
         _ssd_chunk_kernel,
         out_shape=(
@@ -250,22 +307,15 @@ def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, chunk: int = 128,
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
             jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bh, ci: (bh, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda bh, ci: (bh, ci, 0, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
-        ),
+        grid=g.grid,
+        in_specs=[g.head(P), g.head_row(), g.group(N), g.group(N),
+                  g.head_a()],
+        out_specs=(g.head(P), g.head_state((P, N)), g.head_row()),
+        scratch_shapes=[pltpu.VMEM((chunk, chunk), jnp.float32)],  # C·Bᵀ
+        compiler_params=g.params(),
         interpret=interpret,
         name="ssd_chunk_pallas",
-    )(xf, dtf, bf, cf, af)
+    )(xf, dtf, Bm.reshape(Bsz, S, G * N), Cm.reshape(Bsz, S, G * N), af)
 
     y = jnp.swapaxes(y.reshape(Bsz, H, S, P), 1, 2)
     states = jnp.swapaxes(states.reshape(Bsz, H, nc, P, N), 1, 2)

@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import dispatch
 from repro.kernels.ssd import ref as _ref
 from repro.kernels.ssd.kernel import ssd_chunk_pallas, ssd_chunk_pallas_bwd
@@ -233,6 +234,10 @@ def ssd_scan(x, dt, A, Bm, Cm, D=None, *, init_state=None, chunk: int = 128,
     if d.impl == "naive":
         return _ref.ssd_ref(x, dt, A, Bm, Cm, D, init_state)
     if d.impl == "pallas":
+        if d.variant == "mosaic":
+            # heads over groups: how many heads share one C·Bᵀ
+            obs.count("ssd.heads", x.shape[0] * x.shape[2])
+            obs.count("ssd.groups", x.shape[0] * Bm.shape[2])
         return _pallas_ssd(x, dt, A, Bm, Cm, D, init_state, chunk,
                            d.variant, d.design, d.interpret)
     return _chunked_reference(x, dt, A, Bm, Cm, D, chunk, init_state)

@@ -25,8 +25,16 @@ SHAPES = [
     (1, 64, 8, 32, 4, 16),
 ]
 
+# many heads share one group's B, C and C·Bᵀ in the Mosaic kernels; the
+# last has one head per group
+SHARED = [
+    (1, 64, 16, 8, 1, 4),
+    (2, 64, 8, 16, 2, 8),
+    (1, 64, 4, 8, 4, 4),
+]
 
-@pytest.mark.parametrize("shape", SHAPES)
+
+@pytest.mark.parametrize("shape", SHAPES + SHARED)
 @pytest.mark.parametrize("impl", ["reference", "pallas"])
 def test_matches_oracle(shape, impl):
     x, dt, A, Bm, Cm, D = _mk(*shape)
@@ -112,12 +120,30 @@ def test_pallas_backward_kernel_all_operands():
                                    rtol=1e-3)
 
 
-def test_pallas_intra_backward_matches_vjp():
+@pytest.mark.parametrize("shape", SHARED)
+def test_pallas_gradients_with_heads_sharing_a_group(shape):
+    """dx, ddt, dA, dB and dC against oracle autodiff where the kernels sum
+    dB / dC over many heads of a group (or over one, G == H)."""
+    x, dt, A, Bm, Cm, D = _mk(*shape)
+
+    def loss(impl):
+        def f(x, dt, A, Bm, Cm):
+            y, s = ssd_scan(x, dt, A, Bm, Cm, D, impl=impl, chunk=32)
+            return (y ** 2).mean() + (s ** 2).mean()
+        return f
+
+    args = (x, dt, A, Bm, Cm)
+    g0 = jax.grad(loss("naive"), argnums=(0, 1, 2, 3, 4))(*args)
+    g1 = jax.grad(loss("pallas"), argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(g0, g1):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-4,
+                                   rtol=1e-3)
+
+
+def _check_intra_backward(B, S, H, P, G, N, chunk, seed):
     from repro.kernels.ssd.kernel import ssd_chunk_pallas_bwd
     from repro.kernels.ssd.ops import _intra_chunk_jnp
-    key = jax.random.PRNGKey(3)
-    B, S, H, P, G, N, chunk = 1, 64, 2, 8, 1, 4, 32
-    ks = jax.random.split(key, 8)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     x = jax.random.normal(ks[0], (B, S, H, P))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
     A = -jnp.exp(jax.random.normal(ks[2], (H,)))
@@ -134,3 +160,30 @@ def test_pallas_intra_backward_matches_vjp():
     for a, b in zip(want, got):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-4,
                                    rtol=1e-4)
+
+
+def test_pallas_intra_backward_matches_vjp():
+    _check_intra_backward(1, 64, 2, 8, 1, 4, chunk=32, seed=3)
+
+
+@pytest.mark.parametrize("shape", SHARED)
+def test_pallas_intra_backward_heads_sharing_a_group(shape):
+    """(dx, ddt, dA, dB, dC) of the intra-chunk kernel vs its jnp twin's
+    VJP, with dB / dC gathered over the group's heads inside the kernel."""
+    _check_intra_backward(*shape, chunk=32, seed=5)
+
+
+def test_counters_heads_and_groups():
+    """A traced Mosaic call adds B·H to ``ssd.heads`` and B·G to
+    ``ssd.groups``: how many heads share one C·Bᵀ."""
+    from repro import obs
+    x, dt, A, Bm, Cm, D = (jax.ShapeDtypeStruct(a.shape, a.dtype)
+                           for a in _mk(2, 64, 8, 16, 2, 8))
+    before = obs.counter("ssd.heads"), obs.counter("ssd.groups")
+    jax.eval_shape(lambda *a: ssd_scan(*a, impl="pallas", chunk=32),
+                   x, dt, A, Bm, Cm, D)
+    assert (obs.counter("ssd.heads") - before[0],
+            obs.counter("ssd.groups") - before[1]) == (16, 4)
+    jax.eval_shape(lambda *a: ssd_scan(*a, impl="reference", chunk=32),
+                   x, dt, A, Bm, Cm, D)
+    assert obs.counter("ssd.heads") - before[0] == 16

@@ -135,6 +135,37 @@ def test_ssd_fwd_bwd_mamba2_widths(one_chip):
         _sds((B, nc, H, P_, N), jnp.float32, one_chip), dt)
 
 
+def test_ssd_fwd_bwd_granite_widths(one_chip, monkeypatch):
+    """granite-4.0-h-micro's Mamba-2 mixer in its training cell (B=2,
+    S=4096, 64 heads x 64 on one group of d_state 128, chunk 256), forward
+    and backward through the public op at the TPU lowering: both kernels
+    compile under their declared names, and the counters record 64 heads
+    sharing each of the two sequences' group."""
+    from repro import obs
+    from repro.kernels import dispatch
+    from repro.kernels.ssd.ops import ssd_scan
+
+    monkeypatch.setattr(dispatch, "current_backend", lambda: "tpu")
+    B, S, H, P_, G, N, chunk = 2, 4096, 64, 64, 1, 128, 256
+    x = _sds((B, S, H, P_), jnp.bfloat16, one_chip)
+    dt = _sds((B, S, H), jnp.float32, one_chip)
+    A = _sds((H,), jnp.float32, one_chip)
+    bc = _sds((B, S, G, N), jnp.bfloat16, one_chip)
+    y = _sds((B, S, H, P_), jnp.float32, one_chip)
+    final = _sds((B, H, P_, N), jnp.float32, one_chip)
+
+    def scan(x, dt, A, b, c):
+        return ssd_scan(x, dt, A, b, c, chunk=chunk)
+
+    before = obs.counter("ssd.heads"), obs.counter("ssd.groups")
+    compiled = _compile(lambda x, dt, A, b, c, dy, ds: jax.vjp(
+        scan, x, dt, A, b, c)[1]((dy, ds)), x, dt, A, bc, bc, y, final)
+    assert (obs.counter("ssd.heads") - before[0],
+            obs.counter("ssd.groups") - before[1]) == (128, 2)
+    assert {_declared(n) for n in _kernel_calls(compiled.as_text())} == {
+        "ssd_chunk_pallas", "ssd_chunk_pallas_bwd"}
+
+
 def test_swa_avg_fold(one_chip):
     a = _sds((1 << 20,), jnp.float32, one_chip)
     _compile(lambda a, w, n: running_average_pallas(a, w, n,
